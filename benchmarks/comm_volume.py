@@ -16,6 +16,10 @@ import subprocess
 import sys
 from typing import Dict
 
+# Measured on simulated host devices: this process and the child it
+# starts (which inherits the variable) never take an accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 _SNIPPET = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -62,6 +66,8 @@ for variant in ("baseline", "zeropp", "qwz", "hpz", "qgz"):
         "projected_by_label": step_wire_by_label(
             model.comm_events(), model.zcfg, sizes),
     }
+from repro.obs.report import device_info
+out["device"] = device_info()
 print("RESULT " + json.dumps(out))
 """
 
@@ -107,6 +113,7 @@ def main(csv=True):
 
     print("# Table 1 (measured wire bytes from compiled HLO, 8 devices)")
     m = measured()
+    print(f"# device: {m['device']}")
     base_slow = None
     print("variant,slow_tier_bytes,fast_tier_bytes,reduction_slow")
     for variant in ("baseline", "zeropp", "qwz", "hpz", "qgz"):

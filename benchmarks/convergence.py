@@ -19,6 +19,10 @@ import os
 import subprocess
 import sys
 
+# Measured on simulated host devices: this process and the child it
+# starts (which inherits the variable) never take an accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 _SNIPPET = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -32,7 +36,7 @@ from repro.optim.schedule import warmup_cosine
 from repro.train import trainer as trainer_lib
 from repro.train.policy import make_policy
 from repro.train.trainer import init_state, place_batch
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 STEPS = int(os.environ.get("CONV_STEPS", "40"))
 arch = get_config("gpt-350m").reduced()
@@ -57,6 +61,8 @@ for name, variant, overrides in [
         params, opt, m = ts.fn(params, opt, b)
         losses.append(float(m["loss"]))
     out[name] = losses
+from repro.obs.report import device_info
+out["device"] = device_info()
 print("RESULT " + json.dumps(out))
 """
 
@@ -80,6 +86,8 @@ out = {"die_at": DIE_AT, "restarts": hit["restarts"],
        "writer_stats": hit["writer_stats"],
        "oracle": [oracle["losses"][i] for i in range(STEPS)],
        "interrupted": [hit["losses"][i] for i in range(STEPS)]}
+from repro.obs.report import device_info
+out["device"] = device_info()
 print("RESULT " + json.dumps(out))
 """
 
@@ -112,6 +120,7 @@ def run_elastic(steps: int = 24):
 
 def main_elastic(steps: int = 24):
     out = run_elastic(steps)
+    print(f"# device: {out['device']}")
     o, h = out["oracle"], out["interrupted"]
     print(f"# elastic: worker death at step {out['die_at']} "
           f"(restarts={out['restarts']}) vs uninterrupted")
@@ -129,6 +138,7 @@ def main_elastic(steps: int = 24):
 
 def main(steps: int = 40):
     out = run(steps)
+    print(f"# device: {out['device']}")
     b = out["baseline"]
     z = out["zeropp"]
     n = out["zeropp_nonblocked"]

@@ -10,7 +10,7 @@ real Pallas kernel bodies through the interpreter, pallas when on TPU):
     ops.dequant_reduce_quant call.
   * dequant_gemm — the serving head.  STAGED = dequantize the whole INT8
     weight matrix to bf16 then einsum; FUSED = ops.dequant_matmul (scales
-    applied inside the k-tile loop, no bf16 weight matrix in HBM).
+    applied per weight tile in VMEM, no bf16 weight matrix in HBM).
   * quantize_int8_gbps — blocked quant throughput of a big weight tensor.
 
 Plus backend-independent ANALYTIC HBM-traffic ratios for both fusions.

@@ -29,6 +29,10 @@ import subprocess
 import sys
 from typing import Dict
 
+# Measured on simulated host devices: this process and the child it
+# starts (which inherits the variable) never take an accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 _SNIPPET = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -62,6 +66,8 @@ for key, arch in (("dense", "gpt-350m"), ("moe", "deepseek-moe-16b")):
                 1 for l in ov["per_loop"].values()
                 if not l["has_compute"]),
         }
+from repro.obs.report import device_info
+out["device"] = device_info()
 print("RESULT " + json.dumps(out))
 """
 
@@ -106,9 +112,11 @@ def projection() -> Dict:
 
 def main():
     measured = measure()
-    res = {"measured": measured, "projection": projection(),
-           "operating_point": measured.pop("operating_point", None)}
+    res = {"device": measured.pop("device"),
+           "operating_point": measured.pop("operating_point", None),
+           "measured": measured, "projection": projection()}
     print("BENCH " + json.dumps({"overlap": res}))
+    print(f"# device: {res['device']}")
     print(f"\n{'stack':<6} {'pf':>3} {'struct':>8} {'effective':>10} "
           f"{'slack':>6} {'async':>6} {'bare loops':>10}")
     for stack, by_pf in res["measured"].items():

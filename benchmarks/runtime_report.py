@@ -28,6 +28,10 @@ import subprocess
 import sys
 from typing import Dict
 
+# Measured on simulated host devices: this process and the child it
+# starts (which inherits the variable) never take an accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 _SNIPPET = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -51,7 +55,7 @@ out = train_loop(args)           # raises GateFailure on >1% comm drift
 gate = out["gate"]
 
 from repro.configs import get_config
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.serve import ServeEngine
 from repro.train.policy import make_policy
@@ -83,6 +87,8 @@ doc = export_snapshot(extra={
                          "mesh": [4, 2], "steps": 4},
                "serve": {"arch": "qwen3-0.6b", "mesh": [2, 4],
                          "slots": 2, "requests": 3}}})
+from repro.obs.report import device_info
+doc["device"] = device_info()
 print("RESULT " + json.dumps(doc))
 """
 
@@ -116,6 +122,7 @@ def main():
     doc = measure()
     rt = doc["runtime"]
     print("BENCH " + json.dumps(doc))
+    print(f"# device: {doc['device']}")
 
     gate = rt["gate"]
     assert gate["ok"], gate           # belt-and-braces: subprocess asserted

@@ -42,6 +42,10 @@ import subprocess
 import sys
 from typing import Dict
 
+# Measured on simulated host devices: this process and the child it
+# starts (which inherits the variable) never take an accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 _SNIPPET = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -51,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from repro.configs import get_config
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.serve import ServeEngine
 from repro.train.policy import make_policy
@@ -101,6 +105,8 @@ for variant in ("baseline", "qwz"):
         res["occupancy"][occ] = {"step_s": med,
                                  "decode_tok_per_s": occ / med}
     out[variant] = res
+from repro.obs.report import device_info
+out["device"] = device_info()
 print("RESULT " + json.dumps(out))
 """
 
@@ -114,7 +120,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from repro.configs import get_config
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.serve import ServeEngine
 from repro.train.policy import make_policy
@@ -219,6 +225,8 @@ spec.run(max_steps=200)
 acc = spec.stats()["spec_accepted"]
 out["spec"] = {"accepted_mean": acc["mean"], "rounds": acc["n"],
                "completed": spec.stats()["completed"]}
+from repro.obs.report import device_info
+out["device"] = device_info()
 print("RESULT " + json.dumps(out))
 """
 
@@ -251,8 +259,9 @@ def measure_trace() -> Dict:
 
 
 def _structural(trace: Dict) -> Dict:
-    """The deterministic scheduling facts — everything but wall-clock."""
-    return {k: v for k, v in trace.items() if k != "wall"}
+    """The deterministic scheduling facts — everything but wall-clock and
+    the device it ran on."""
+    return {k: v for k, v in trace.items() if k not in ("wall", "device")}
 
 
 def _gate(trace: Dict) -> None:
@@ -278,7 +287,8 @@ def main(smoke: bool = False, write_snapshot: bool = False):
     print("BENCH " + json.dumps(out))
 
     if not smoke:
-        res = out["serve"]
+        res = dict(out["serve"])
+        print(f"# device: {res.pop('device')}")
         print(f"\n{'variant':<10} {'ttft_ms':>9}  " +
               "  ".join(f"occ={o:>2} tok/s" for o in
                         sorted(int(k) for k in res['baseline']['occupancy'])))
@@ -290,7 +300,8 @@ def main(smoke: bool = False, write_snapshot: bool = False):
 
     eq = trace["equal_hbm"]
     w = trace["wall"]
-    print(f"\n# multi-tenant trace (equal HBM: {eq['kv_positions']} KV "
+    print(f"\n# multi-tenant trace on {trace['device']}")
+    print(f"# multi-tenant trace (equal HBM: {eq['kv_positions']} KV "
           f"positions)")
     print(f"peak concurrent: slab={eq['slab_peak_concurrent']} "
           f"paged={eq['paged_peak_concurrent']} "

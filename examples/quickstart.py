@@ -19,7 +19,7 @@ from repro.models.model import Model
 from repro.optim.adamw import AdamWConfig
 from repro.train import trainer
 from repro.train.policy import make_policy
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 
 def main():

@@ -36,7 +36,7 @@ from repro.models.model import Model
 from repro.serve import ServeEngine
 from repro.train.policy import make_policy
 from repro.train.state import ZeroState, param_specs
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 
 def main():

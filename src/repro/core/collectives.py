@@ -33,7 +33,6 @@ from repro.core.quant import (
 # hot-path quantization goes through the kernel dispatcher: Pallas kernels on
 # TPU (incl. the fused reorder+quant and dequant-reduce-quant of paper §4.2),
 # bit-identical pure-jnp on CPU.
-from repro.core.compat import axis_size as _axis_size
 # Module import (not from-import): kernels.ops also reaches back into
 # repro.core lazily, so names must resolve at call time, not import time.
 from repro.kernels import ops as _kops
@@ -63,7 +62,7 @@ def _axes_tuple(axes: Axes) -> Tuple[str, ...]:
 def axis_size(axes: Axes) -> int:
     n = 1
     for a in _axes_tuple(axes):
-        n *= _axis_size(a)
+        n *= lax.axis_size(a)
     return n
 
 
@@ -180,7 +179,7 @@ def flat_rank(axes: Axes) -> Array:
     """This device's rank within the flattened (row-major) axis group."""
     rank = jnp.int32(0)
     for a in _axes_tuple(axes):
-        rank = rank * _axis_size(a) + lax.axis_index(a)
+        rank = rank * lax.axis_size(a) + lax.axis_index(a)
     return rank
 
 
@@ -284,7 +283,7 @@ def qgz_reduce_scatter(
     (not averaged) over the world.
     """
     inter_axes = _axes_tuple(inter_axes) if inter_axes else ()
-    X = _axis_size(intra_axis)
+    X = lax.axis_size(intra_axis)
     Y = axis_size(inter_axes) if inter_axes else 1
     world = X * Y
     n = grad.shape[0]
@@ -388,7 +387,7 @@ def qgz_quantized_ring_reduce_scatter(
     # flatten multi-axis rank
     rank = jnp.int32(0)
     for a in axes_t:
-        rank = rank * _axis_size(a) + lax.axis_index(a)
+        rank = rank * lax.axis_size(a) + lax.axis_index(a)
 
     def hop(i, acc):
         # acc: fp32 (L,) partial sum for slice s_r(i) = (rank - 1 - i) mod W;

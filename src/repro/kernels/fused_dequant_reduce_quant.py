@@ -8,10 +8,11 @@ re-quantized for the next hop.  Running those as three separate ops costs
 touches HBM once per input byte and once per output byte — the fusion the
 paper credits with "reduc[ing] total memory traffic by 9x".
 
-Tiling: grid over the slice length only; the contribution dim N (= GPUs per
-node in the paper, mesh axis size here, <= 32) lives entirely inside the
-tile, so the reduction is a single VMEM-resident ``sum`` over the sublane
-dimension.
+Tiling: each contribution's length-C row is viewed as the same (rows, W)
+slab the quant kernels use (quant_block.slab, sized for N of them per
+step), and the grid walks its row tiles.  The contribution dim N (= GPUs
+per node in the paper, mesh axis size here, <= 32) is the whole leading
+block dim, so the reduction is a single VMEM-resident ``sum`` over it.
 """
 from __future__ import annotations
 
@@ -23,51 +24,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.quant import QuantConfig
-from repro.kernels.quant_block import pick_tiles, _quant_body
+from repro.kernels.quant_block import (_quant_body, _row_spec, _unpack4,
+                                      slab)
 
 Array = jax.Array
 
 
-def _unpack4(p: Array) -> Array:
-    lo = (p << 4) >> 4
-    hi = p >> 4
-    return jnp.stack([lo, hi], axis=-1).reshape(*p.shape[:-1],
-                                                p.shape[-1] * 2)
-
-
-def _dequant_sum(p, s, block: int, pack: bool):
-    """(N, pt) payload + (N, nbt) scales -> (ct,) fp32 sum."""
+def _dequant_sum(p_ref, s_ref, block: int, pack: bool):
+    """(N, rt, pw) payload + (N, rt, nbt) scales -> (rt, W) fp32 sum."""
+    p = p_ref[...]
     if pack:
-        p = _unpack4(p)
-    N, ct = p.shape
-    nb = ct // block
-    deq = p.reshape(N, nb, block).astype(jnp.float32) * s[..., None]
-    return jnp.sum(deq, axis=0).reshape(ct)  # fp32 reduce (accuracy: §3.3)
+        p = jnp.stack([_unpack4(p[n]) for n in range(p.shape[0])])
+    n, rt, w = p.shape
+    deq = p.reshape(n, rt, w // block, block).astype(jnp.float32) \
+        * s_ref[...][..., None]
+    return jnp.sum(deq, axis=0).reshape(rt, w)  # fp32 reduce (§3.3)
 
 
 def _reduce_kernel(p_ref, s_ref, out_ref, *, block, pack, out_dtype):
-    acc = _dequant_sum(p_ref[...], s_ref[...], block, pack)
-    out_ref[...] = acc.astype(out_dtype)[None]
+    out_ref[...] = _dequant_sum(p_ref, s_ref, block, pack).astype(out_dtype)
 
 
-def _reduce_requant_kernel(p_ref, s_ref, out_p_ref, out_s_ref, *,
-                           block, pack_in, qmax_out, pack_out):
-    acc = _dequant_sum(p_ref[...], s_ref[...], block, pack_in)
-    q, s = _quant_body(acc[None], block, qmax_out, pack_out)
+def _reduce_requant_kernel(p_ref, s_ref, *refs, block, pack_in, qmax_out,
+                           pack_out):
+    """With a (rt, W) tile of pre-drawn uniforms before the outputs, the
+    requantization rounds stochastically (core.quant.stochastic_uniform),
+    exactly like the standalone SR quant kernel — the PRNG stays outside
+    the kernel so pallas/interpret/xla round identically per element."""
+    *u_ref, out_p_ref, out_s_ref = refs
+    acc = _dequant_sum(p_ref, s_ref, block, pack_in)
+    u = u_ref[0][...] if u_ref else None
+    q, s = _quant_body(acc, block, qmax_out, pack_out, u=u)
     out_p_ref[...] = q
     out_s_ref[...] = s
 
 
-def _reduce_requant_kernel_sr(p_ref, s_ref, u_ref, out_p_ref, out_s_ref, *,
-                              block, pack_in, qmax_out, pack_out):
-    """Stochastic-rounding variant: the requantization consumes a (1, ct)
-    tile of pre-drawn uniforms (core.quant.stochastic_uniform), exactly
-    like the standalone SR quant kernel — the PRNG stays outside the
-    kernel so pallas/interpret/xla round identically per element."""
-    acc = _dequant_sum(p_ref[...], s_ref[...], block, pack_in)
-    q, s = _quant_body(acc[None], block, qmax_out, pack_out, u=u_ref[...])
-    out_p_ref[...] = q
-    out_s_ref[...] = s
+def _contrib_specs(n: int, rt: int, pw: int, nbt: int):
+    return [pl.BlockSpec((n, rt, pw), lambda i: (0, i, 0)),
+            pl.BlockSpec((n, rt, nbt), lambda i: (0, i, 0))]
 
 
 def dequant_reduce_pallas(payload: Array, scales: Array, cfg: QuantConfig,
@@ -81,24 +75,19 @@ def dequant_reduce_pallas(payload: Array, scales: Array, cfg: QuantConfig,
     pack = cfg.bits == 4
     C = P * 2 if pack else P
     block = cfg.block_size
-    _, ct = pick_tiles(1, C, block)
-    nbt = ct // block
-    pt = ct // 2 if pack else ct
-    grid = (C // ct,)
+    rows, w, rt = slab(C, block, depth=N)
+    pw = w // 2 if pack else w
     kernel = functools.partial(_reduce_kernel, block=block, pack=pack,
                                out_dtype=out_dtype)
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((N, pt), lambda j: (0, j)),
-            pl.BlockSpec((N, nbt), lambda j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, ct), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((1, C), out_dtype),
+        grid=(pl.cdiv(rows, rt),),
+        in_specs=_contrib_specs(N, rt, pw, w // block),
+        out_specs=_row_spec(rt, w),
+        out_shape=jax.ShapeDtypeStruct((rows, w), out_dtype),
         interpret=interpret,
-    )(payload, scales)
-    return out[0]
+    )(payload.reshape(N, rows, pw), scales.reshape(N, rows, w // block))
+    return out.reshape(C)
 
 
 def dequant_reduce_quant_pallas(
@@ -122,39 +111,28 @@ def dequant_reduce_quant_pallas(
     pack_out = cfg_out.bits == 4
     C = P * 2 if pack_in else P
     block = cfg_in.block_size
-    _, ct = pick_tiles(1, C, block)
-    nbt = ct // block
-    pt_in = ct // 2 if pack_in else ct
-    pt_out = ct // 2 if pack_out else ct
-    grid = (C // ct,)
-    in_specs = [
-        pl.BlockSpec((N, pt_in), lambda j: (0, j)),
-        pl.BlockSpec((N, nbt), lambda j: (0, j)),
-    ]
-    operands = [payload, scales]
-    if u is None:
-        kernel = functools.partial(_reduce_requant_kernel, block=block,
-                                   pack_in=pack_in, qmax_out=cfg_out.qmax,
-                                   pack_out=pack_out)
-    else:
+    rows, w, rt = slab(C, block, depth=N)
+    pw_in = w // 2 if pack_in else w
+    pw_out = w // 2 if pack_out else w
+    in_specs = _contrib_specs(N, rt, pw_in, w // block)
+    operands = [payload.reshape(N, rows, pw_in),
+                scales.reshape(N, rows, w // block)]
+    if u is not None:
         assert u.shape == (C,), (u.shape, C)
-        kernel = functools.partial(_reduce_requant_kernel_sr, block=block,
-                                   pack_in=pack_in, qmax_out=cfg_out.qmax,
-                                   pack_out=pack_out)
-        in_specs.append(pl.BlockSpec((1, ct), lambda j: (0, j)))
-        operands.append(u.reshape(1, C))
+        in_specs.append(_row_spec(rt, w))
+        operands.append(u.reshape(rows, w))
+    kernel = functools.partial(_reduce_requant_kernel, block=block,
+                               pack_in=pack_in, qmax_out=cfg_out.qmax,
+                               pack_out=pack_out)
     out_p, out_s = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(pl.cdiv(rows, rt),),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, pt_out), lambda j: (0, j)),
-            pl.BlockSpec((1, nbt), lambda j: (0, j)),
-        ],
+        out_specs=[_row_spec(rt, pw_out), _row_spec(rt, w // block)],
         out_shape=[
-            jax.ShapeDtypeStruct((1, C // 2 if pack_out else C), jnp.int8),
-            jax.ShapeDtypeStruct((1, C // block), jnp.float32),
+            jax.ShapeDtypeStruct((rows, pw_out), jnp.int8),
+            jax.ShapeDtypeStruct((rows, w // block), jnp.float32),
         ],
         interpret=interpret,
     )(*operands)
-    return out_p[0], out_s[0]
+    return out_p.reshape(-1), out_s.reshape(-1)

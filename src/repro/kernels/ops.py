@@ -197,7 +197,7 @@ def dequant_matmul(x: Array, payload: Array, scales: Array,
     Dequantized weights round through ``compute_dtype`` (bf16) before the
     MXU — exactly the staged gather-dequant-einsum math — so the ``xla``
     backend is bit-identical to the staged path; the kernel applies the
-    scales inside its k-tile loop (INT8 rows stream from HBM at 1 B/elem,
+    scales per weight tile in VMEM (INT8 rows stream from HBM at 1 B/elem,
     never materializing the bf16 weight matrix).
     """
     mode = backend()

@@ -12,11 +12,17 @@ touch jax device state (the dry-run pins the device count before first use).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
 
-from repro.core.compat import auto_axis_types, make_mesh
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the shard_map code here
+    names its collectives explicitly and lets the compiler place the rest."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         (jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,8 +41,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     devs = jax.devices()
     if len(devs) > n:
         devs = devs[:n]
-    return make_mesh(shape, axes, devices=devs,
-                     axis_types=auto_axis_types(len(axes)))
+    return make_mesh(shape, axes, devices=devs)
 
 
 def make_test_mesh(shape: Tuple[int, ...] = None, axes: Tuple[str, ...] = None):
@@ -49,7 +54,7 @@ def make_test_mesh(shape: Tuple[int, ...] = None, axes: Tuple[str, ...] = None):
             shape, axes = (n // 2, 2), ("data", "model")
         else:
             shape, axes = (1, n), ("data", "model")
-    return make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def mesh_axis_names(mesh) -> Tuple[str, ...]:

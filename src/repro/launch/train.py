@@ -28,6 +28,28 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+# the persistent compile cache's home when $JAX_COMPILATION_CACHE_DIR is
+# unset: a fixed path inside the checkout (<repo>/src/repro/launch/ -> <repo>)
+DEFAULT_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))),
+    ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Switch on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself and
+    nothing is set here); otherwise the cache lives at
+    :data:`DEFAULT_CACHE_DIR`.  Call before the first compile — entry
+    points do, importing this module does not.
+    """
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 @dataclasses.dataclass
 class Built:
@@ -68,10 +90,10 @@ def build_everything(arch_name: str, mesh_shape: Tuple[int, ...],
     from repro.train import trainer as trainer_lib
     from repro.train.policy import make_policy
 
-    from repro.core.compat import auto_axis_types, make_mesh
+    from repro.launch.mesh import make_mesh
     axes = ("data", "model") if len(mesh_shape) == 2 \
         else ("pod", "data", "model")
-    mesh = make_mesh(mesh_shape, axes, axis_types=auto_axis_types(len(axes)))
+    mesh = make_mesh(mesh_shape, axes)
     arch = get_config(arch_name)
     if reduced:
         arch = arch.reduced()
@@ -168,7 +190,7 @@ def train_loop(args) -> Dict[str, Any]:
     params, opt = st.params, st.opt
 
     b_specs = ts.in_specs[2]
-    losses = []
+    losses, step_seconds = [], []
     t_start = time.time()
     telemetry = bool(getattr(args, "metrics_dir", None))
     tracer = _setup_telemetry(args)
@@ -204,12 +226,14 @@ def train_loop(args) -> Dict[str, Any]:
         tracer.profiler_annotations = (i - start) < trace_steps
         t_step = time.monotonic_ns()
         with tracer.span("train.step", step=i):
-            params, opt, metrics = ts.fn(params, opt, batch)
+            params, opt, metrics = jax.block_until_ready(
+                ts.fn(params, opt, batch))
             loss = float(metrics["loss"])
+        step_seconds.append((time.monotonic_ns() - t_step) / 1e9)
         losses.append(loss)
         if telemetry:
-            wall_ms = (time.monotonic_ns() - t_step) / 1e6
-            reg.histogram("train.step.wall_ms").observe(wall_ms)
+            reg.histogram("train.step.wall_ms").observe(
+                step_seconds[-1] * 1e3)
             reg.counter("train.steps").inc()
             reg.counter("train.tokens").inc(float(metrics["tokens"]))
             for lbl, b in comm.items():
@@ -255,9 +279,13 @@ def train_loop(args) -> Dict[str, Any]:
         print(f"[train] obs gate {ok}: comm labels "
               f"{sorted((comm or {}))} vs analytic projection "
               f"(BENCH -> {args.metrics_dir}/BENCH_runtime.json)")
+    st.params, st.opt, st.step = params, opt, args.steps
     return {"losses": losses, "entropy_bound": lm.entropy_bound,
             "final_loss": losses[-1] if losses else None,
-            "gate": gate_report}
+            "gate": gate_report,
+            # host wall time of each step, device work included; the
+            # first one of a process also holds the step's compilation
+            "step_seconds": step_seconds, "state": st}
 
 
 def run_elastic(args) -> None:
@@ -314,12 +342,9 @@ def run_elastic(args) -> None:
           f"last_loss={last if last is None else f'{last:.4f}'}")
 
 
-def main():
-    # before any jax import: let the backend's latency-hiding scheduler
-    # exploit the prefetched schedule (core/schedule.py, launch/xla_flags.py)
-    from repro.launch.xla_flags import enable_overlap_flags
-    enable_overlap_flags(os.environ.get("REPRO_PLATFORM", "cpu"))
-
+def build_parser() -> argparse.ArgumentParser:
+    """The training CLI; ``parse_args([...])`` also gives library callers
+    (chip_smoke.py) the CLI's defaults."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt-350m")
     ap.add_argument("--reduced", action="store_true",
@@ -390,7 +415,16 @@ def main():
                     help="quant-kernel backend (kernels/ops.py); default "
                          "resolves $REPRO_KERNEL_BACKEND then platform "
                          "(pallas on TPU, xla elsewhere)")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    # before any jax import: let the backend's scheduler exploit the
+    # prefetched schedule (core/schedule.py, launch/xla_flags.py)
+    from repro.launch.xla_flags import enable_overlap_flags
+    enable_overlap_flags()
+    enable_compile_cache()
+    args = build_parser().parse_args()
 
     if args.kernel_backend is not None:
         from repro.kernels import ops as kops

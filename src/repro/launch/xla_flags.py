@@ -3,14 +3,15 @@
 core/schedule.py arranges the program so that each scan iteration's
 collectives are data-independent of its matmuls (verified structurally by
 hlo_analysis.analyze_overlap).  Turning that freedom into wall-clock
-overlap is the latency-hiding scheduler's job, and it is backend-specific:
+overlap is the latency-hiding scheduler's job:
 
-  * TPU/GPU — the LHS pass rewrites collectives into async start/done
-    pairs and hoists the starts above independent compute.  These are the
-    flags the paper's DeepSpeed runs effectively rely on (NCCL streams).
+  * TPU — libtpu runs its latency-hiding scheduler by default, so nothing
+    is set.  TPU compiler flags belong in ``LIBTPU_INIT_ARGS``, never in
+    ``XLA_FLAGS``: jaxlib parses ``XLA_FLAGS`` and aborts the process on a
+    ``--xla_tpu_*`` name it does not know.
   * CPU — no LHS pass exists; the thunk runtime's concurrency-optimized
-    scheduler is the closest analogue.  The schedule is still *verified*
-    on CPU via the dependence analysis; it just is not timed there.
+    scheduler is the closest analogue.  jaxlib always carries the CPU
+    backend, so this flag parses on every platform and is inert off CPU.
 
 ``enable_overlap_flags()`` must run before the first jax import in the
 process (XLA reads the env once at backend init) — launch/train.py calls
@@ -19,46 +20,19 @@ it at the top of ``main()``.
 from __future__ import annotations
 
 import os
-from typing import Optional, Sequence
 
-# NOTE: XLA aborts the process on unknown/malformed flags, so each list
-# holds only flags valid for that platform's jaxlib: the gpu/cpu lists are
-# verified to parse against this repo's pinned jaxlib; --xla_tpu_* flags
-# exist only in libtpu builds (passing platform="tpu" on a CPU/GPU jaxlib
-# WILL abort at backend init — that is XLA's behaviour, not a typo here).
-OVERLAP_FLAGS = {
-    "tpu": (
-        "--xla_tpu_enable_latency_hiding_scheduler=true",
-    ),
-    "gpu": (
-        "--xla_gpu_enable_latency_hiding_scheduler=true",
-        "--xla_gpu_enable_highest_priority_async_stream=true",
-    ),
-    "cpu": (
-        "--xla_cpu_enable_concurrency_optimized_scheduler=true",
-    ),
-}
+OVERLAP_FLAGS = ("--xla_cpu_enable_concurrency_optimized_scheduler=true",)
 
 
-def overlap_xla_flags(platform: str = "cpu") -> Sequence[str]:
-    """The latency-hiding flags for ``platform`` (tpu | gpu | cpu)."""
-    return OVERLAP_FLAGS.get(platform, ())
-
-
-def enable_overlap_flags(platform: str = "cpu",
-                         env: Optional[dict] = None) -> str:
-    """Append the platform's overlap flags to XLA_FLAGS (idempotent).
-
-    Returns the resulting XLA_FLAGS value.  ``env`` defaults to
-    ``os.environ``; pass a dict to build a subprocess environment instead.
-    """
-    env = os.environ if env is None else env
-    parts = env.get("XLA_FLAGS", "").split()
+def enable_overlap_flags() -> str:
+    """Append :data:`OVERLAP_FLAGS` to ``$XLA_FLAGS`` (idempotent) and
+    return the result."""
+    parts = os.environ.get("XLA_FLAGS", "").split()
     present = {p.split("=", 1)[0] for p in parts}
-    for flag in overlap_xla_flags(platform):
+    for flag in OVERLAP_FLAGS:
         # match on the flag NAME: a user-set opposite value wins, we never
         # append a duplicate that would silently override it
         if flag.split("=", 1)[0] not in present:
             parts.append(flag)
-    env["XLA_FLAGS"] = " ".join(parts)
-    return env["XLA_FLAGS"]
+    os.environ["XLA_FLAGS"] = " ".join(parts)
+    return os.environ["XLA_FLAGS"]

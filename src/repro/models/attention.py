@@ -16,7 +16,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from repro.core.compat import axis_size as _axis_size
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -59,7 +58,7 @@ def seq_shard_offset(s_local: int, seq_axes: Sequence[str]) -> Array:
     """Global position of this device's first sequence element."""
     off = jnp.int32(0)
     for ax in seq_axes:
-        off = off * _axis_size(ax) + lax.axis_index(ax)
+        off = off * lax.axis_size(ax) + lax.axis_index(ax)
     return off * s_local
 
 
@@ -330,7 +329,7 @@ def decode_attend(
 def _kv_axes_world(kv_seq_axes: Sequence[str]) -> int:
     w = 1
     for ax in kv_seq_axes:
-        w *= _axis_size(ax)
+        w *= lax.axis_size(ax)
     return w
 
 

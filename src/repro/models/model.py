@@ -621,8 +621,8 @@ class Model:
         Vc, d = self.vchunk, cfg.d_model
         if qwz_gemm_eligible(z, Vc, d):
             # fused head: gather the qwZ payload WITHOUT dequantizing and
-            # let the dequant-GEMM kernel apply the scales in its k-tile
-            # loop — the bf16 (Vc, d) chunk never materializes.  The unemb
+            # let the dequant-GEMM kernel apply the scales per weight
+            # tile in VMEM — the bf16 (Vc, d) chunk never materializes.  The unemb
             # entry sits at flat offset 0 of its spec, so payload rows are
             # a plain reshape; the two eligible scale layouts are per-row
             # groups (d % block == 0) or one-block-covers-whole-rows

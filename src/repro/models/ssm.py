@@ -16,7 +16,6 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from repro.core.compat import axis_size as _axis_size
 
 Array = jax.Array
 
@@ -45,7 +44,7 @@ def shard_prefix_state(decay_total: Array, state_final: Array,
     n = d.shape[0]
     rank = jnp.int32(0)
     for ax in axes:
-        rank = rank * _axis_size(ax) + lax.axis_index(ax)
+        rank = rank * lax.axis_size(ax) + lax.axis_index(ax)
     # sequential prefix over the (static, small) shard count:
     # h_in(0)=0; h_in(k) = d_{k-1}·h_in(k-1) + s_{k-1}
     h_all = [jnp.zeros_like(state_final)]
@@ -67,7 +66,7 @@ def gather_conv_halo(x: Array, taps: int, seq_axes: Sequence[str]) -> Array:
     n = t.shape[0]
     rank = jnp.int32(0)
     for ax in seq_axes:
-        rank = rank * _axis_size(ax) + lax.axis_index(ax)
+        rank = rank * lax.axis_size(ax) + lax.axis_index(ax)
     prev = jnp.where(rank > 0, jnp.clip(rank - 1, 0, n - 1), 0)
     halo = t[prev]  # (B, taps, C)
     return jnp.where(rank > 0, halo, jnp.zeros_like(halo))
@@ -180,7 +179,7 @@ def _total_prefix_decay(decay_dev: Array, seq_axes: Sequence[str]) -> Array:
     n = d.shape[0]
     rank = jnp.int32(0)
     for ax in seq_axes:
-        rank = rank * _axis_size(ax) + lax.axis_index(ax)
+        rank = rank * lax.axis_size(ax) + lax.axis_index(ax)
     cum = jnp.cumprod(d, axis=0)
     prefix = jnp.concatenate([jnp.ones_like(cum[:1]), cum[:-1]], axis=0)
     return prefix[rank]

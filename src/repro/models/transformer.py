@@ -34,7 +34,6 @@ from repro.models import attention as attn
 from repro.models import layers as nn
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
-from repro.core.compat import axis_size as _axis_size
 
 Array = jax.Array
 
@@ -206,7 +205,7 @@ def _chunk_for(S: int, chunk: int) -> int:
 def _axes_prod(axes: Sequence[str]) -> int:
     n = 1
     for a in axes:
-        n *= _axis_size(a)
+        n *= lax.axis_size(a)
     return n
 
 
@@ -332,7 +331,7 @@ def _last_shard_value(x: Array, seq_axes: Sequence[str]) -> Array:
     n = _axes_prod(seq_axes)
     rank = jnp.int32(0)
     for ax in seq_axes:
-        rank = rank * _axis_size(ax) + lax.axis_index(ax)
+        rank = rank * lax.axis_size(ax) + lax.axis_index(ax)
     sel = (rank == n - 1).astype(x.dtype)
     return lax.psum(x * sel, tuple(seq_axes))
 

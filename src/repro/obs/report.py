@@ -41,11 +41,19 @@ from repro.obs.metrics import Registry, get_registry
 
 __all__ = ["export_snapshot", "bench_diff", "format_diff",
            "comm_gate", "overhead_gate", "runtime_gate",
-           "projected_wire_by_label", "GateFailure"]
+           "projected_wire_by_label", "GateFailure", "device_info"]
 
 
 class GateFailure(AssertionError):
     """A measured-vs-projected check exceeded its tolerance."""
+
+
+def device_info() -> Dict[str, Any]:
+    """The devices a result was measured on, as JAX reports them."""
+    import jax
+    d = jax.devices()
+    return {"platform": d[0].platform, "kind": d[0].device_kind,
+            "count": len(d)}
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives as cl
-from repro.core.compat import make_mesh, shard_map, auto_axis_types
+from repro.launch.mesh import make_mesh
 from repro.core.quant import QuantConfig, quantize_blockwise, dequantize_blockwise
 
 
@@ -22,16 +22,14 @@ def _mesh2(data: int = None, model: int = 2):
     n = jax.device_count()
     data = data or n // model
     assert data * model == n, f"need data*model == {n}"
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=auto_axis_types(2))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def _mesh3(pod: int = 2, model: int = 2):
     n = jax.device_count()
     data = n // (pod * model)
     assert pod * data * model == n
-    return make_mesh((pod, data, model), ("pod", "data", "model"),
-                     axis_types=auto_axis_types(3))
+    return make_mesh((pod, data, model), ("pod", "data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -44,10 +42,10 @@ def _qgz_vs_oracle(mesh, intra_axis, inter_axes, all_axes, bits, block, n_per_de
     g = rng.normal(size=(world * n_per_dev,)).astype(np.float32)
     cfg = QuantConfig(bits=bits, block_size=block)
 
-    f_qgz = jax.jit(shard_map(
+    f_qgz = jax.jit(jax.shard_map(
         lambda x: cl.qgz_reduce_scatter(x, intra_axis, inter_axes, cfg),
         mesh=mesh, in_specs=P(all_axes), out_specs=P(all_axes)))
-    f_ora = jax.jit(shard_map(
+    f_ora = jax.jit(jax.shard_map(
         lambda x: cl.baseline_reduce_scatter(x.astype(jnp.float32), all_axes),
         mesh=mesh, in_specs=P(all_axes), out_specs=P(all_axes)))
 
@@ -102,10 +100,10 @@ def check_qgz_exact_when_representable():
     ranks = (np.arange(world, dtype=np.float32) + 1.0)[:, None]
     g = (ranks * pattern[None, :]).reshape(-1)  # device d shard = (d+1)*P
 
-    f_qgz = jax.jit(shard_map(
+    f_qgz = jax.jit(jax.shard_map(
         lambda x: cl.qgz_reduce_scatter(x, "model", ("data",), cfg),
         mesh=mesh, in_specs=P(("data", "model")), out_specs=P(("data", "model"))))
-    f_ora = jax.jit(shard_map(
+    f_ora = jax.jit(jax.shard_map(
         lambda x: cl.baseline_reduce_scatter(x, ("data", "model")),
         mesh=mesh, in_specs=P(("data", "model")), out_specs=P(("data", "model"))))
     got = np.asarray(f_qgz(jnp.asarray(g)))
@@ -120,12 +118,12 @@ def check_qgz_1hop_and_ring():
     rng = np.random.default_rng(2)
     g = rng.normal(size=(world * 32 * world,)).astype(np.float32)
     spec = P(("data", "model"))
-    f1 = jax.jit(shard_map(lambda x: cl.qgz_reduce_scatter_1hop(x, ("data", "model"), cfg),
-                           mesh=mesh, in_specs=spec, out_specs=spec))
-    fr = jax.jit(shard_map(lambda x: cl.qgz_quantized_ring_reduce_scatter(x, ("data", "model"), cfg),
-                           mesh=mesh, in_specs=spec, out_specs=spec))
-    fo = jax.jit(shard_map(lambda x: cl.baseline_reduce_scatter(x, ("data", "model")),
-                           mesh=mesh, in_specs=spec, out_specs=spec))
+    f1 = jax.jit(jax.shard_map(lambda x: cl.qgz_reduce_scatter_1hop(x, ("data", "model"), cfg),
+                               mesh=mesh, in_specs=spec, out_specs=spec))
+    fr = jax.jit(jax.shard_map(lambda x: cl.qgz_quantized_ring_reduce_scatter(x, ("data", "model"), cfg),
+                               mesh=mesh, in_specs=spec, out_specs=spec))
+    fo = jax.jit(jax.shard_map(lambda x: cl.baseline_reduce_scatter(x, ("data", "model")),
+                               mesh=mesh, in_specs=spec, out_specs=spec))
     want = np.asarray(fo(jnp.asarray(g)))
     got1 = np.asarray(f1(jnp.asarray(g)))
     gotr = np.asarray(fr(jnp.asarray(g)))
@@ -150,7 +148,7 @@ def check_qwz_all_gather():
     rng = np.random.default_rng(3)
     w = (rng.normal(size=(world * 256,)) * 0.02).astype(np.float32)
     spec = P(("data", "model"))
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda x: cl.qwz_all_gather(x, ("data", "model"), cfg, out_dtype=jnp.float32),
         mesh=mesh, in_specs=spec, out_specs=P(None), check_vma=False))
     got = np.asarray(f(jnp.asarray(w)))
@@ -160,7 +158,7 @@ def check_qwz_all_gather():
     # blocked must beat non-blocked on heterogeneous-scale data (Fig. 2)
     w2 = w.copy()
     w2[: world * 8] *= 100.0  # outlier block
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda x: cl.qwz_all_gather(x, ("data", "model"), cfg,
                                     out_dtype=jnp.float32, blocked=False),
         mesh=mesh, in_specs=spec, out_specs=P(None), check_vma=False))
@@ -183,9 +181,9 @@ def check_hpz_roundtrip():
         full2 = cl.hpz_all_gather(sec, "model")
         return full2
 
-    got = np.asarray(jax.jit(shard_map(f, mesh=mesh, in_specs=spec,
-                                       out_specs=P(None),
-                                       check_vma=False))(jnp.asarray(w)))
+    got = np.asarray(jax.jit(jax.shard_map(f, mesh=mesh, in_specs=spec,
+                                           out_specs=P(None),
+                                           check_vma=False))(jnp.asarray(w)))
     np.testing.assert_allclose(got, w, rtol=0, atol=0)
 
 
@@ -227,7 +225,7 @@ def _engine_grads(mesh, zcfg, w_flat, x, spec, layer_f, loss_of):
         l, g = jax.value_and_grad(lf)(pshard)
         return lax.psum(l, zcfg.dp_axes), g
 
-    fstep = jax.jit(shard_map(
+    fstep = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(("data", "model")), P(("data", "model"), None, None)),
         out_specs=(P(), P(("data", "model")))))
@@ -946,9 +944,9 @@ def _stack_loss_and_grads(pf: int, arch_name: str, n_layers: int = 0):
         l, g = jax.value_and_grad(lf)(p)
         return lax.psum(l, z.dp_axes), g
 
-    sm = shard_map(gf, mesh=mesh,
-                   in_specs=(ts.in_specs[0], ts.in_specs[2]),
-                   out_specs=(P(), ts.in_specs[0]), check_vma=False)
+    sm = jax.shard_map(gf, mesh=mesh,
+                       in_specs=(ts.in_specs[0], ts.in_specs[2]),
+                       out_specs=(P(), ts.in_specs[0]), check_vma=False)
     loss, grads = jax.jit(sm)(params, b)
     return float(loss), {k: np.asarray(v) for k, v in grads.items()}
 
@@ -1086,7 +1084,7 @@ def check_qgz_1hop_rejects_misaligned():
     spec = P(("data", "model"))
     n_bad = world * (world * 32 + 8)  # local len not divisible by world*32
     g = jnp.ones((n_bad,), jnp.float32)
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda x: cl.qgz_reduce_scatter_1hop(x, ("data", "model"), cfg),
         mesh=mesh, in_specs=spec, out_specs=spec))
     try:
@@ -1529,8 +1527,8 @@ def check_kernel_backend_train_bitexact():
 
 def check_qwz_gemm_head_matches_staged():
     """The fused INT8 dequant-GEMM serving head (qwz_gemm=True: the decode
-    GEMM eats the gathered INT8 payload, scales applied in the k-tile
-    loop) must produce the same logits as the staged
+    GEMM eats the gathered INT8 payload, scales applied per weight
+    tile) must produce the same logits as the staged
     gather-dequant-einsum head (qwz_gemm=False) — tight allclose (fp32
     accumulation-order only) and identical argmax, under both the xla
     and interpret backends."""
@@ -1716,16 +1714,21 @@ def check_obs_runtime_gate():
         params, opt, m = ts.fn(params, opt, batch)       # compile once
         jax.block_until_ready(m["loss"])
 
-        # -- overhead: alternate plain steps with telemetry-DISABLED
-        # steps (no-op span + False guard — the production off path);
-        # medians over interleaved samples cancel machine drift
+        # -- overhead: interleave plain steps with telemetry-DISABLED
+        # steps (no-op span + False guard — the production off path) in
+        # ABBA order (off, plain, plain, off, ...) so neither side always
+        # runs first; medians over interleaved samples cancel machine drift.
+        # 256 samples a side: with 8 or 24, the medians of two identical
+        # ~10 ms CPU steps differ by more than 2 % in a quarter to a half
+        # of runs on a loaded host
         plain_s, off_s = [], []
         telemetry = False
-        for i in range(1, 17):
+        n_timed = 512
+        for i in range(1, n_timed + 1):
             host = make_batch(arch, lm, i, 16)
             batch = place_batch(host, mesh, ts.in_specs[2])
             t0 = _time.monotonic()
-            if i % 2:
+            if i % 4 in (0, 1):
                 with off.span("train.step", step=i):
                     params, opt, m = ts.fn(params, opt, batch)
                     jax.block_until_ready(m["loss"])
@@ -1740,7 +1743,7 @@ def check_obs_runtime_gate():
         # -- a couple of fully-ENABLED steps: counters must accumulate
         # exactly measured-per-step * n_steps
         n_enabled = 2
-        for i in range(17, 17 + n_enabled):
+        for i in range(n_timed + 1, n_timed + 1 + n_enabled):
             host = make_batch(arch, lm, i, 16)
             batch = place_batch(host, mesh, ts.in_specs[2])
             with tracer.span("train.step", step=i):

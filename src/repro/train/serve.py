@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.models.model import Model
 from repro.models.transformer import RunSpec
 # specs come from the state subsystem, not the trainer: serving must not
@@ -116,10 +115,10 @@ def build_prefill_step(model: Model, mesh,
             return model.prefill_fn(params, batch, rs)
         in_specs = (p_specs, b_specs)
 
-    sm = shard_map(stepf, mesh=mesh,
-                   in_specs=in_specs,
-                   out_specs=(logit_spec, c_specs),
-                   check_vma=False)
+    sm = jax.shard_map(stepf, mesh=mesh,
+                       in_specs=in_specs,
+                       out_specs=(logit_spec, c_specs),
+                       check_vma=False)
     return ServeStep(fn=jax.jit(sm), mesh=mesh,
                      in_specs=in_specs,
                      out_specs=(logit_spec, c_specs), run_spec=rs)
@@ -156,10 +155,10 @@ def build_decode_step(model: Model, mesh,
     def stepf(params, caches, batch, cache_pos):
         return model.decode_fn(params, caches, batch, cache_pos, rs)
 
-    sm = shard_map(stepf, mesh=mesh,
-                   in_specs=(p_specs, c_specs, b_specs, pos_spec),
-                   out_specs=(logit_spec, c_specs),
-                   check_vma=False)
+    sm = jax.shard_map(stepf, mesh=mesh,
+                       in_specs=(p_specs, c_specs, b_specs, pos_spec),
+                       out_specs=(logit_spec, c_specs),
+                       check_vma=False)
     fn = jax.jit(sm, donate_argnums=(1,) if donate else ())
     return ServeStep(fn=fn, mesh=mesh,
                      in_specs=(p_specs, c_specs, b_specs, pos_spec),
@@ -221,10 +220,10 @@ def build_paged_step(model: Model, mesh,
         return model.paged_fn(params, caches, batch, table, start_pos, rs)
 
     in_specs = (p_specs, c_specs, b_specs, table_spec, pos_spec)
-    sm = shard_map(stepf, mesh=mesh,
-                   in_specs=in_specs,
-                   out_specs=(logit_spec, c_specs),
-                   check_vma=False)
+    sm = jax.shard_map(stepf, mesh=mesh,
+                       in_specs=in_specs,
+                       out_specs=(logit_spec, c_specs),
+                       check_vma=False)
     fn = jax.jit(sm, donate_argnums=(1,) if donate else ())
     return ServeStep(fn=fn, mesh=mesh, in_specs=in_specs,
                      out_specs=(logit_spec, c_specs), run_spec=rs)

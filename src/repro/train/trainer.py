@@ -21,7 +21,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.models.model import Model
 from repro.models.transformer import RunSpec
 from repro.optim.adamw import AdamWConfig, apply_update
@@ -167,7 +166,7 @@ def build_train_step(
                 / (model.n_moe_layers * world * max(accum, 1))
         return new_params, new_opt, out_m
 
-    sm = shard_map(
+    sm = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(p_specs, o_specs, b_specs),
         out_specs=(p_specs, o_specs, m_specs),

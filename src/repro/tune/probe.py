@@ -168,7 +168,6 @@ def _time_collective(mesh, axis: str, n_local: int, iters: int,
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
 
     g = int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis])
     if kind == "gather":
@@ -181,8 +180,8 @@ def _time_collective(mesh, axis: str, n_local: int, iters: int,
             return lax.all_to_all(x, axis, split_axis=0, concat_axis=0)
         x = jnp.ones((g * g, n_local), jnp.bfloat16)
         in_specs, out_specs = P(axis), P(axis)
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs))
     jax.block_until_ready(fn(x))   # compile + warm up outside the clock
     best = float("inf")
     for _ in range(iters):

@@ -19,14 +19,13 @@ from repro.testing.subproc import run_checks
 def _tiny_state():
     import jax
     from repro.configs import get_config
-    from repro.core.compat import auto_axis_types, make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.model import Model
     from repro.optim.adamw import AdamWConfig
     from repro.train.policy import make_policy
     from repro.train.state import ZeroState
 
-    mesh = make_mesh((1, 1), ("data", "model"),
-                     axis_types=auto_axis_types(2))
+    mesh = make_mesh((1, 1), ("data", "model"))
     arch = get_config("gpt-350m").reduced()
     pol = make_policy(arch, tuple(mesh.axis_names))
     model = Model(arch, pol.zcfg, world=1)

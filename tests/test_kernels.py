@@ -19,9 +19,9 @@ from repro.core.quant import QuantConfig, dequantize_blockwise
 from repro.kernels import ref
 from repro.kernels.quant_block import (
     dequantize_pallas,
-    pick_tiles,
     quantize_pallas,
     quantize_reordered_pallas,
+    slab,
 )
 from repro.kernels.fused_dequant_reduce_quant import (
     dequant_reduce_pallas,
@@ -180,10 +180,18 @@ def test_prop_fused_reduce_is_fp32_exact(n, nblocks, seed):
 
 
 def test_pick_tiles_divides():
-    for rows, cols, block in [(1, 256, 256), (13, 13 * 512, 128),
-                              (64, 8192, 1024), (5, 640, 64)]:
-        rt, ct = pick_tiles(rows, cols, block)
-        assert rows % rt == 0 and cols % ct == 0 and ct % block == 0
+    """The slab view tiles by the TPU rule: width W divides the buffer and
+    holds whole quant blocks (and whole 256-lane int4 pack groups where the
+    length allows); the row tile is a multiple of 32 or every row."""
+    for n, block, depth in [(256, 256, 1), (13 * 512, 128, 1),
+                            (64 * 8192, 1024, 1), (5 * 640, 64, 1),
+                            (4194816, 256, 1), (16779264, 256, 1),
+                            (8389632, 256, 2), (3 * 384, 128, 16)]:
+        rows, w, rt = slab(n, block, depth)
+        assert rows * w == n and w % block == 0
+        if n % 256 == 0:
+            assert w % 256 == 0
+        assert rt == rows or (rt % 32 == 0 and rt < rows)
 
 
 def test_ops_dispatch_ref_equals_interpret():
@@ -210,21 +218,22 @@ def test_ops_dispatch_ref_equals_interpret():
 from repro.kernels.dequant_matmul import dequant_matmul_pallas
 
 GEMM_SWEEP = [
-    # (T, N, K, n_scale_groups, x_dtype) — single- and multi-k-tile,
-    # per-row scale groups and the one-scale-per-row broadcast layout
+    # (T, N, K, n_scale_groups, x_dtype) — single and several token/row
+    # tiles, per-row scale groups and the one-scale-per-row broadcast layout
     (8, 64, 256, 2, jnp.float32),
     (4, 128, 64, 1, jnp.bfloat16),
     (16, 96, 384, 3, jnp.float32),
     (3, 40, 512, 4, jnp.bfloat16),
-    (16, 128, 2048, 8, jnp.float32),   # k-tiled: 4 accumulation steps
-    (5, 64, 1536, 12, jnp.float32),    # odd row count, k-tiled
+    (16, 128, 2048, 8, jnp.float32),   # wide contraction
+    (5, 64, 1536, 12, jnp.float32),    # odd row count
+    (300, 640, 2048, 8, jnp.bfloat16),  # partial last token and row tiles
 ]
 
 
 @pytest.mark.parametrize("T,N,K,nb,xdtype", GEMM_SWEEP)
 def test_dequant_matmul_matches_ref(T, N, K, nb, xdtype):
-    """Kernel vs staged oracle.  Tolerance is fp32 accumulation ORDER only
-    (k-tiled partial sums); the elementwise dequant math is identical."""
+    """Kernel vs staged oracle.  Tolerance is fp32 accumulation ORDER only;
+    the elementwise dequant math is identical."""
     cfg = QuantConfig(bits=8, block_size=K // nb)
     x = _rand((T, K), xdtype, seed=T * K)
     w = _rand((N, K), jnp.float32, seed=N + K)

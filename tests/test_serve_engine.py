@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
 from repro.configs import get_config
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.serve import FIFOScheduler, Request, ServeEngine, steps
 from repro.serve import sampling

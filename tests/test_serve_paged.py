@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
 from repro.configs import get_config
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.serve import PagedKVPool, ServeEngine, steps
 from repro.testing.subproc import run_checks

@@ -45,3 +45,35 @@ def test_serve_consistency_families():
 @pytest.mark.slow
 def test_dryrun_machinery():
     run_checks(["check_dryrun_smoke_cell"], n_devices=8, timeout=900)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache goes to one fixed directory in the checkout."""
+    import os
+
+    import jax
+    from repro.launch import train
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/from/env")
+    assert train.enable_compile_cache() == "/from/env"
+    assert jax.config.jax_compilation_cache_dir == was
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = train.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(repo, ".jax_cache")
+
+
+def test_overlap_flags_never_put_tpu_flags_in_xla_flags(monkeypatch):
+    """jaxlib aborts on an --xla_tpu_* name in XLA_FLAGS; the flags the
+    launcher adds parse on every platform, once."""
+    from repro.launch.xla_flags import enable_overlap_flags
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
+    flags = enable_overlap_flags().split()
+    assert flags[0] == "--xla_force_host_platform_device_count=2"
+    assert not any(f.startswith("--xla_tpu_") for f in flags)
+    assert enable_overlap_flags().split() == flags
