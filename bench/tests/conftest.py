@@ -1,0 +1,10 @@
+"""The benchmark's CPU tests import the benchmark as ``bench`` and the
+program from ``src``."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (os.path.join(ROOT, "src"), ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
