@@ -131,8 +131,7 @@ kernel-smoke:
 # telemetry-under-failure jsonl replay (kill/restart -> totals equal the
 # uninterrupted oracle), and the runtime gate on a REAL 8-dev train run
 # (comm bytes within 1% of the analytic projection, telemetry-disabled
-# overhead < 2%), then the telemetry-on train + serve BENCH report with
-# the gate in assert mode
+# overhead < 2%)
 obs-smoke:
 	$(PYPATH) $(PY) -c "\
 	from repro.testing.subproc import run_checks; \
@@ -143,7 +142,6 @@ obs-smoke:
 	            'check_obs_runtime_gate'], n_devices=8, timeout=1800); \
 	print('obs smoke OK: comm counters match analytics, replay survives '\
 	      'kill/restart, runtime gate passes')"
-	$(PYPATH) $(PY) -m benchmarks.runtime_report
 
 # tuner smoke (repro/tune, DESIGN.md §9): the (k+1)-ring HBM ledger vs
 # the MEASURED live gathered-buffer counts in the traced train step for

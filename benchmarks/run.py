@@ -8,9 +8,6 @@
   overlap_bench    — measured schedule overlap vs ring depth (BENCH json:
                      structural + depth-credited fractions, async pairs,
                      break-even depth projection; 8-dev subprocess)
-  runtime_report   — telemetry-on train + serve run (obs/): BENCH snapshot
-                     with the measured-vs-projected comm gate in assert
-                     mode, serve latency percentiles, dispatch counts
   tuner_report     — static boot-time resolution sweep (repro.tune):
                      resolved knobs + (k+1)-ring HBM ledger + break-even
                      depth per arch x mesh, checked against the committed
@@ -37,8 +34,7 @@ import traceback
 def main() -> None:
     from benchmarks import (comm_volume, convergence, kernel_bench,
                             memory_model, overlap_bench, roofline,
-                            runtime_report, serve_bench, throughput_model,
-                            tuner_report)
+                            serve_bench, throughput_model, tuner_report)
     sections = {
         "comm_volume": comm_volume.main,
         "throughput_model": throughput_model.main,
@@ -46,7 +42,6 @@ def main() -> None:
         "memory_model": memory_model.main,
         "convergence": convergence.main,
         "overlap_bench": overlap_bench.main,
-        "runtime_report": runtime_report.main,
         "tuner_report": tuner_report.main,
         "serve_bench": serve_bench.main,
     }

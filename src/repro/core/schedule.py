@@ -75,6 +75,7 @@ from jax import lax
 
 from repro.core import collectives as cl
 from repro.core.zeropp import ZeroConfig, fwd_gather, grad_reduce, zero_apply
+from repro.obs.trace import annotate
 
 Array = jax.Array
 
@@ -134,12 +135,22 @@ def _bwd_src(stacked: Array, res_ws, z: ZeroConfig):
 # ring plumbing (shared by the fwd/bwd scans)
 # ---------------------------------------------------------------------------
 
+# ``step.ring_*`` scopes name the ring's own operations in the profiler
+# trace's op names (bench/scopes.py); they change no instruction.
+
 def _ring_read(ring: Array, slot: Array) -> Array:
-    return lax.dynamic_index_in_dim(ring, slot, axis=0, keepdims=False)
+    with annotate("step.ring_read"):
+        return lax.dynamic_index_in_dim(ring, slot, axis=0, keepdims=False)
 
 
 def _ring_write(ring: Array, buf: Array, slot: Array) -> Array:
-    return lax.dynamic_update_index_in_dim(ring, buf, slot, axis=0)
+    with annotate("step.ring_write"):
+        return lax.dynamic_update_index_in_dim(ring, buf, slot, axis=0)
+
+
+def _ring_seed(slots: Sequence[Array]) -> Array:
+    with annotate("step.ring_seed"):
+        return jnp.stack(slots)
 
 
 def _bwd_ring_seed(src, n: int, k: int, gather: Callable) -> Array:
@@ -151,7 +162,7 @@ def _bwd_ring_seed(src, n: int, k: int, gather: Callable) -> Array:
             lambda s: lax.dynamic_index_in_dim(s, i, axis=0, keepdims=False),
             src)
         slots[i % k] = gather(p)
-    return jnp.stack(slots)
+    return _ring_seed(slots)
 
 
 def _ring_grad_tail(dWring_f: Array, dprevs: Array, n: int, k: int,
@@ -162,7 +173,8 @@ def _ring_grad_tail(dWring_f: Array, dprevs: Array, n: int, k: int,
     reduce (the top k slots were the dummy zero-reduces)."""
     head = [grad_reduce(dWring_f[j], z)[None].astype(dprevs.dtype)
             for j in range(k)]
-    return jnp.concatenate(head + [dprevs[: n - k]], axis=0)
+    with annotate("step.ring_tail"):
+        return jnp.concatenate(head + [dprevs[: n - k]], axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -269,10 +281,10 @@ def _prefetched(f: Callable, z: ZeroConfig, f_fwd, f_bwd, spec, bwd_spec,
         # then reads slot i%k (layer i) and refills it with layer i+k
         seed = [W0 if (j == 0 and W0 is not None)
                 else fwd_gather(stacked[j], z) for j in range(k)]
-        ring0 = jnp.stack(seed)
+        ring0 = _ring_seed(seed)
         if spec is not None:
-            sring0 = jnp.stack([fwd_gather(spec(xs, j), z)
-                                for j in range(k)])
+            sring0 = _ring_seed([fwd_gather(spec(xs, j), z)
+                                 for j in range(k)])
 
         def body(carry, sx):
             if spec is not None:
@@ -299,12 +311,14 @@ def _prefetched(f: Callable, z: ZeroConfig, f_fwd, f_bwd, spec, bwd_spec,
             else:
                 h2, y = f(W, h, x, *bargs)
                 aux = None
-            if z.hpz:
-                # re-partition the gathered weights into this device's
-                # secondary shard: zero extra communication (paper §3.2.1)
-                res_w = cl.slice_secondary(W, z.secondary_axes)
-            else:
-                res_w = jnp.zeros((0,), W.dtype)  # bwd re-gathers primary
+            with annotate("step.residual"):
+                if z.hpz:
+                    # re-partition the gathered weights into this
+                    # device's secondary shard: zero extra communication
+                    # (paper §3.2.1)
+                    res_w = cl.slice_secondary(W, z.secondary_axes)
+                else:
+                    res_w = jnp.zeros((0,), W.dtype)  # bwd re-gathers primary
             ring2 = _ring_write(ring, W_next, slot)
             # joint pin: in-flight gathers and compute all finish inside
             # this iteration but stay mutually independent (overlappable)
@@ -363,14 +377,15 @@ def _prefetched(f: Callable, z: ZeroConfig, f_fwd, f_bwd, spec, bwd_spec,
             sw_slots: List[Optional[Array]] = [None] * k
             for j in range(n - k, n):
                 sw_slots[j % k] = _bwd_gather(bwd_spec(auxs, j), z)
-            sWring0 = jnp.stack(sw_slots)
+            sWring0 = _ring_seed(sw_slots)
         zero_b = jax.tree.map(
             lambda v: jnp.zeros(v.shape, v.dtype), bargs_f)
         # dW of layer i+k rides a second ring: its reduce-scatter runs
         # inside layer i's iteration, overlapped with the recompute+vjp.
         # The first k (i = n-1..n-k) iterations reduce zeros (discarded).
         full = stacked.shape[1] * cl.axis_size(z.dp_axes)
-        dWring0 = jnp.zeros((k, full), jnp.float32)
+        with annotate("step.ring_seed"):
+            dWring0 = jnp.zeros((k, full), jnp.float32)
         aux_xs = auxs if f_bwd is not None \
             else jnp.zeros((n,), jnp.float32)
 
@@ -559,7 +574,7 @@ def _chunk_hpz_vjp(f: Callable, z: ZeroConfig, _gather, w0_meta):
 
         seed = [W0 if (j == 0 and w0_meta is not None)
                 else _gather(sec[j]) for j in range(k)]
-        ring0 = jnp.stack(seed)
+        ring0 = _ring_seed(seed)
 
         def body(ring, sx):
             i, x = sx
@@ -606,7 +621,8 @@ def _chunk_hpz_vjp(f: Callable, z: ZeroConfig, _gather, w0_meta):
         else:
             Wring0 = _bwd_ring_seed(sec, nc, k, _gather)
             full = stacked.shape[1] * cl.axis_size(z.dp_axes)
-            dWring0 = jnp.zeros((k, full), jnp.float32)
+            with annotate("step.ring_seed"):
+                dWring0 = jnp.zeros((k, full), jnp.float32)
 
             def body(carry, sx):
                 Wring, dWring, bg = carry
@@ -679,10 +695,10 @@ def zero_scan_inference(f: Callable, z: ZeroConfig, *,
 
         seed = [W0 if (j == 0 and W0 is not None)
                 else fwd_gather(stacked[j], z) for j in range(k)]
-        ring0 = jnp.stack(seed)
+        ring0 = _ring_seed(seed)
         if spec is not None:
-            sring0 = jnp.stack([fwd_gather(spec(xs, j), z)
-                                for j in range(k)])
+            sring0 = _ring_seed([fwd_gather(spec(xs, j), z)
+                                 for j in range(k)])
 
         def body(carry, sx):
             if spec is not None:

@@ -196,17 +196,6 @@ def train_loop(args) -> Dict[str, Any]:
     tracer = _setup_telemetry(args)
     trace_steps = int(getattr(args, "trace_steps", 0) or 0)
     reg = get_registry()
-    if telemetry:
-        # record the chosen policy so dashboards can segment runs by knob
-        z = pol.zcfg
-        reg.gauge("tune.prefetch").set(z.prefetch)
-        reg.gauge("tune.qwz").set(int(z.qwz))
-        reg.gauge("tune.hpz").set(int(z.hpz))
-        reg.gauge("tune.qgz").set(int(z.qgz))
-        reg.gauge("tune.qwz_block").set(z.qwz_block)
-        reg.gauge("tune.qgz_block").set(z.qgz_block)
-        reg.gauge("tune.mode").set(
-            {"off": 0, "static": 1, "probe": 2}.get(tune, 0))
     comm = None   # {label: per-device bytes/step}, filled on first step
     for i in range(start, args.steps):
         if args.simulate_failure_at is not None \
@@ -256,29 +245,17 @@ def train_loop(args) -> Dict[str, Any]:
                       fmt=args.ckpt_format)
     gate_report = None
     if telemetry:
-        from repro.obs.report import (export_snapshot,
-                                      projected_wire_by_label, runtime_gate)
+        from repro.obs.report import projected_wire_by_label, runtime_gate
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         projected = projected_wire_by_label(model, sizes, accum=args.accum)
         gate_report = runtime_gate(
             measured=comm or {}, projected=projected,
             strict=bool(getattr(args, "obs_gate", False)))
-        policy_dict = (pol.as_dict() if hasattr(pol, "as_dict")
-                       else {"mode": "off", "prefetch": pol.zcfg.prefetch,
-                             "note": pol.note})
-        export_snapshot(
-            os.path.join(args.metrics_dir, "BENCH_runtime.json"),
-            extra={"gate": gate_report,
-                   "policy": policy_dict,
-                   "config": {"arch": arch.name, "variant": args.variant,
-                              "mesh": list(mesh_shape), "tune": tune,
-                              "steps": args.steps, "batch": args.batch,
-                              "seq": args.seq, "accum": args.accum}})
         tracer.close()
         ok = "PASS" if gate_report["ok"] else "FAIL"
         print(f"[train] obs gate {ok}: comm labels "
               f"{sorted((comm or {}))} vs analytic projection "
-              f"(BENCH -> {args.metrics_dir}/BENCH_runtime.json)")
+              f"(events -> {args.metrics_dir}/events.jsonl)")
     st.params, st.opt, st.step = params, opt, args.steps
     return {"losses": losses, "entropy_bound": lm.entropy_bound,
             "final_loss": losses[-1] if losses else None,
@@ -389,11 +366,10 @@ def build_parser() -> argparse.ArgumentParser:
                     help="sleep this long inside every shard write")
     ap.add_argument("--fault-flaky-writes", type=int, default=None,
                     help="fail the first N shard writes with OSError")
-    # telemetry (obs/): jsonl event log, metrics registry, BENCH export
+    # telemetry (obs/): jsonl event log, metrics registry, comm gate
     ap.add_argument("--metrics-dir", default=None,
-                    help="enable telemetry: write events.jsonl + "
-                         "BENCH_runtime.json here (default: disabled, "
-                         "zero-overhead no-op tracer)")
+                    help="enable telemetry: write events.jsonl here "
+                         "(default: disabled, zero-overhead no-op tracer)")
     ap.add_argument("--trace-steps", type=int, default=0,
                     help="wrap the first N steps in jax.profiler "
                          "TraceAnnotations (requires --metrics-dir)")

@@ -36,6 +36,7 @@ from repro.models import moe as moe_lib
 from repro.models.transformer import (RunSpec, apply_block, block_entries,
                                       expert_entries, init_cache_shapes,
                                       moe_pre_block, _sub)
+from repro.obs.trace import annotate
 
 Array = jax.Array
 
@@ -472,16 +473,46 @@ class Model:
                 rs: RunSpec, dp_world: int) -> Tuple[Array, Dict[str, Array]]:
         """Local loss (sum-NLL / global token count).  psum-able."""
         cfg, z = self.cfg, self.zcfg
-        if cfg.embed_inputs:
-            h = batch["embeds"].astype(z.compute_dtype)
-        else:
-            toks = batch["tokens"]
-            emb_f = lambda W, t: self.embed_spec.unpack(W)["emb"][t] \
-                .astype(z.compute_dtype)
-            h = zero_apply(emb_f, z)(params["embed"], toks)
-        B, S_loc = h.shape[0], h.shape[1]
-        cos, sin = self._rope_tables(batch, rs, S_loc)
+        # step.* scopes name the step's phases in the profiler trace's op
+        # names (bench/scopes.py); the backward of each appears as
+        # transpose(jvp(step.*)).  They change no instruction.
+        with annotate("step.embed"):
+            if cfg.embed_inputs:
+                h = batch["embeds"].astype(z.compute_dtype)
+            else:
+                toks = batch["tokens"]
+                emb_f = lambda W, t: self.embed_spec.unpack(W)["emb"][t] \
+                    .astype(z.compute_dtype)
+                h = zero_apply(emb_f, z)(params["embed"], toks)
+            B, S_loc = h.shape[0], h.shape[1]
+            cos, sin = self._rope_tables(batch, rs, S_loc)
         global_tokens = float(B * S_loc * dp_world)
+
+        with annotate("step.layers"):
+            h, aux = self._train_layers(params, h, cos, sin, rs)
+
+        def norm_fn(W, h):
+            p = self.head_spec.unpack(W.astype(z.compute_dtype))
+            return nn.rms_norm(h, p["fnorm"])
+
+        with annotate("step.loss"):
+            hn = zero_apply(norm_fn, z)(params["head"], h)
+            nll_sum = self._streaming_xent(
+                lambda f: zero_apply(f, z), params["unemb"],
+                hn.reshape(-1, cfg.d_model), batch["targets"].reshape(-1))
+            loss = nll_sum / global_tokens
+            metrics = {"nll_sum": nll_sum,
+                       "tokens": jnp.float32(B * S_loc)}
+            if self.n_moe_layers:
+                aux_mean = aux / (self.n_moe_layers * dp_world)
+                loss = loss + cfg.aux_loss_weight * aux_mean
+                metrics["moe_aux"] = aux
+        return loss, metrics
+
+    def _train_layers(self, params, h, cos, sin, rs: RunSpec):
+        """The training layer scan (and the remainder layers) over ``h``:
+        returns the last hidden state and the summed auxiliary loss."""
+        cfg, z = self.cfg, self.zcfg
 
         def period_fn(W, h, cos, sin, spec=self.period_spec, kinds=self.period):
             p = spec.unpack(W.astype(z.compute_dtype))
@@ -547,22 +578,7 @@ class Model:
                         kinds=self.period[: self.rem]), z)
             h, aux_r = ap_rem(params["rem"], h, cos, sin)
             aux = aux + aux_r
-
-        def norm_fn(W, h):
-            p = self.head_spec.unpack(W.astype(z.compute_dtype))
-            return nn.rms_norm(h, p["fnorm"])
-
-        hn = zero_apply(norm_fn, z)(params["head"], h)
-        nll_sum = self._streaming_xent(
-            lambda f: zero_apply(f, z), params["unemb"],
-            hn.reshape(-1, cfg.d_model), batch["targets"].reshape(-1))
-        loss = nll_sum / global_tokens
-        metrics = {"nll_sum": nll_sum, "tokens": jnp.float32(B * S_loc)}
-        if self.n_moe_layers:
-            aux_mean = aux / (self.n_moe_layers * dp_world)
-            loss = loss + cfg.aux_loss_weight * aux_mean
-            metrics["moe_aux"] = aux
-        return loss, metrics
+        return h, aux
 
     # -------------------------------------------------------------- head
 

@@ -1,30 +1,16 @@
-"""BENCH snapshot export, snapshot diffing, and the measured-vs-projected
-regression gate (DESIGN.md §8).
+"""The measured-vs-projected regression gate (DESIGN.md §8).
 
-Three pieces:
-
-* :func:`export_snapshot` — serialize the metrics registry into the
-  repo's BENCH json schema (one top-level section key, nested plain
-  dicts — the same shape ``benchmarks/snapshots/BENCH_*.json`` already
-  use), optionally merged with caller-provided extras (gate results,
-  run config) and written to disk.
-
-* :func:`bench_diff` — compare two BENCH snapshots leaf-by-leaf and
-  report relative drift.  Also a tiny CLI:
-  ``python -m repro.obs.report diff OLD.json NEW.json [--rel-tol 0.05]``.
-
-* The gate — :func:`comm_gate` checks recorded per-step wire bytes
-  (jaxpr walk, ``wire_by_label``) against the analytic projection
-  (``Model.comm_events`` folded through ``zeropp.step_wire_by_label``)
-  per collective label at a strict default tolerance of 1%;
-  :func:`overhead_gate` checks the telemetry-disabled step time against
-  a no-telemetry baseline (medians of interleaved samples, so CI noise
-  hits both sides alike); :func:`runtime_gate` combines them into one
-  pass/fail report.  Tolerance policy: comm bytes are DETERMINISTIC
-  (both sides count the same traced program), so 1% is generous — the
-  validated repo configurations match to the byte and any real drift
-  means one side's model is wrong; wall-clock comparisons are loose
-  because CPU CI timing is noisy.
+:func:`comm_gate` checks recorded per-step wire bytes (jaxpr walk,
+``wire_by_label``) against the analytic projection (``Model.comm_events``
+folded through ``zeropp.step_wire_by_label``) per collective label at a
+strict default tolerance of 1%; :func:`overhead_gate` checks the
+telemetry-disabled step time against a no-telemetry baseline (medians of
+interleaved samples, so CI noise hits both sides alike);
+:func:`runtime_gate` combines them into one pass/fail report.  Tolerance
+policy: comm bytes are DETERMINISTIC (both sides count the same traced
+program), so 1% is generous — the validated repo configurations match to
+the byte and any real drift means one side's model is wrong; wall-clock
+comparisons are loose because CPU CI timing is noisy.
 
 This module deliberately imports nothing from jax or the rest of
 ``repro`` at module scope — gate helpers that need the analytic model
@@ -32,15 +18,9 @@ import lazily — so it stays importable from lightweight tooling.
 """
 from __future__ import annotations
 
-import argparse
-import json
-import sys
-from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Any, Dict, Mapping, Optional, Sequence
 
-from repro.obs.metrics import Registry, get_registry
-
-__all__ = ["export_snapshot", "bench_diff", "format_diff",
-           "comm_gate", "overhead_gate", "runtime_gate",
+__all__ = ["comm_gate", "overhead_gate", "runtime_gate",
            "projected_wire_by_label", "GateFailure", "device_info"]
 
 
@@ -54,84 +34,6 @@ def device_info() -> Dict[str, Any]:
     d = jax.devices()
     return {"platform": d[0].platform, "kind": d[0].device_kind,
             "count": len(d)}
-
-
-# ---------------------------------------------------------------------------
-# snapshot export
-# ---------------------------------------------------------------------------
-
-def export_snapshot(path: Optional[str] = None, *,
-                    registry: Optional[Registry] = None,
-                    section: str = "runtime",
-                    extra: Optional[Mapping[str, Any]] = None
-                    ) -> Dict[str, Any]:
-    """Registry -> ``{section: {metrics: <flat snapshot>, **extra}}``.
-
-    The flat metric names (``comm.zero.qwz_gather.bytes``, ...) stay flat
-    under ``"metrics"`` — they are the stable diffable surface; ``extra``
-    carries structured one-off payloads (gate report, run config).
-    """
-    reg = registry if registry is not None else get_registry()
-    body: Dict[str, Any] = {"metrics": reg.snapshot()}
-    if extra:
-        body.update(extra)
-    doc = {section: body}
-    if path:
-        with open(path, "w") as fh:
-            json.dump(doc, fh, indent=2, sort_keys=True)
-            fh.write("\n")
-    return doc
-
-
-# ---------------------------------------------------------------------------
-# snapshot diff
-# ---------------------------------------------------------------------------
-
-def _leaves(doc: Any, prefix: str = "") -> Dict[str, Any]:
-    out: Dict[str, Any] = {}
-    if isinstance(doc, dict):
-        for k, v in doc.items():
-            out.update(_leaves(v, f"{prefix}{k}."))
-        return out
-    out[prefix[:-1]] = doc
-    return out
-
-
-def bench_diff(old: Mapping[str, Any], new: Mapping[str, Any], *,
-               rel_tol: float = 0.05
-               ) -> List[Tuple[str, Any, Any, Optional[float]]]:
-    """Leaf-wise diff of two BENCH docs.
-
-    Returns rows ``(key, old, new, rel)`` for every leaf that drifted
-    beyond ``rel_tol`` (numeric), changed value (non-numeric), or exists
-    on only one side (the missing side is None, rel is None).
-    """
-    a, b = _leaves(dict(old)), _leaves(dict(new))
-    rows: List[Tuple[str, Any, Any, Optional[float]]] = []
-    for key in sorted(set(a) | set(b)):
-        va, vb = a.get(key), b.get(key)
-        if key not in a or key not in b:
-            rows.append((key, va, vb, None))
-            continue
-        if isinstance(va, (int, float)) and isinstance(vb, (int, float)) \
-                and not isinstance(va, bool) and not isinstance(vb, bool):
-            denom = max(abs(va), abs(vb), 1e-12)
-            rel = abs(va - vb) / denom
-            if rel > rel_tol:
-                rows.append((key, va, vb, rel))
-        elif va != vb:
-            rows.append((key, va, vb, None))
-    return rows
-
-
-def format_diff(rows: Sequence[Tuple[str, Any, Any, Optional[float]]]) -> str:
-    if not rows:
-        return "no drift"
-    lines = []
-    for key, va, vb, rel in rows:
-        tail = f"  rel={rel:.3f}" if rel is not None else ""
-        lines.append(f"  {key}: {va!r} -> {vb!r}{tail}")
-    return "\n".join(lines)
 
 
 # ---------------------------------------------------------------------------
@@ -227,28 +129,3 @@ def runtime_gate(*, measured: Mapping[str, float],
                           + "\n  ".join(bad))
     return report
 
-
-# ---------------------------------------------------------------------------
-# CLI
-# ---------------------------------------------------------------------------
-
-def main(argv: Optional[Sequence[str]] = None) -> int:
-    ap = argparse.ArgumentParser(prog="repro.obs.report")
-    sub = ap.add_subparsers(dest="cmd", required=True)
-    d = sub.add_parser("diff", help="compare two BENCH snapshots")
-    d.add_argument("old")
-    d.add_argument("new")
-    d.add_argument("--rel-tol", type=float, default=0.05)
-    d.add_argument("--fail-on-drift", action="store_true")
-    args = ap.parse_args(argv)
-    with open(args.old) as fh:
-        old = json.load(fh)
-    with open(args.new) as fh:
-        new = json.load(fh)
-    rows = bench_diff(old, new, rel_tol=args.rel_tol)
-    print(format_diff(rows))
-    return 1 if (rows and args.fail_on_drift) else 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

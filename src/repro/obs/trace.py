@@ -8,11 +8,16 @@ Two complementary mechanisms, one per timescale:
   Optionally each span also opens a ``jax.profiler.TraceAnnotation`` so
   the spans line up with device activity in a profiler trace.
 * **In-jit labels** (`annotate`): ``jax.named_scope`` wrappers.  These are
-  trace-time only — zero runtime cost — but the labels survive into
-  ``eqn.source_info.name_stack`` of the jaxpr (including through
-  ``lax.scan`` bodies and ``custom_vjp`` transposition, where they appear
-  wrapped as e.g. ``transpose(jvp(zero.hpz_gather))``), which is how
-  ``launch/jaxpr_analysis.py`` attributes per-collective wire bytes.
+  trace-time only — zero runtime cost, no instruction changes — but the
+  labels survive into ``eqn.source_info.name_stack`` of the jaxpr
+  (including through ``lax.scan`` bodies and ``custom_vjp`` transposition,
+  where they appear wrapped as e.g. ``transpose(jvp(zero.hpz_gather))``),
+  which is how ``launch/jaxpr_analysis.py`` attributes per-collective wire
+  bytes.  XLA keeps the name stack as each instruction's ``op_name``, and
+  a device profiler trace carries it as the ``tf_op`` of every operation
+  it ran, which is how ``bench/scopes.py`` splits device time by scope.
+  Instructions XLA makes itself (copy loops, rewritten slices) carry no
+  ``op_name`` and cannot be labelled from here.
 
 Kill-safety / replay contract (elastic training): every flush ends in
 ``os.fsync``; a SIGKILL can at worst truncate the final line, which
@@ -38,9 +43,13 @@ _NULLCTX = contextlib.nullcontext()
 
 def annotate(name: str):
     """In-jit label: a ``jax.named_scope`` whose name survives into the
-    jaxpr ``name_stack``.  Collective wrappers in ``core/collectives.py``
-    use ``zero.<op>`` names; anything outside such a scope is bucketed as
-    ``other`` by the analyzer."""
+    jaxpr ``name_stack`` and into the ``op_name`` of every instruction
+    made under it, which the device trace carries as ``tf_op``.  Names:
+    ``zero.<op>`` on the collective wrappers in ``core/collectives.py``
+    (anything outside such a scope is bucketed as ``other`` by the wire
+    analyzer); ``step.<phase>`` on the train step's phases
+    (``models/model.py``, ``core/schedule.py``'s ring); ``optim.<part>``
+    on the optimizer (``optim/adamw.py``)."""
     return jax.named_scope(name)
 
 

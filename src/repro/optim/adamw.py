@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.obs.trace import annotate
+
 Array = jax.Array
 PyTree = Dict[str, Array]
 
@@ -67,34 +69,38 @@ def apply_update(
 
     Returns (new_params (fp32), new_opt, stats).
     """
-    count = opt["count"] + 1
-    lr = cfg.lr(count) if callable(cfg.lr) else jnp.float32(cfg.lr)
+    # optim.* scopes name the update and its clip in the profiler trace's
+    # op names (bench/scopes.py); they change no instruction.
+    with annotate("optim.adamw"):
+        count = opt["count"] + 1
+        lr = cfg.lr(count) if callable(cfg.lr) else jnp.float32(cfg.lr)
 
-    gnorm = global_grad_norm(grads, dp_axes)
-    scale = jnp.where(gnorm > cfg.grad_clip,
-                      cfg.grad_clip / (gnorm + 1e-12), 1.0) \
-        if cfg.grad_clip else jnp.float32(1.0)
+        with annotate("optim.clip"):
+            gnorm = global_grad_norm(grads, dp_axes)
+            scale = jnp.where(gnorm > cfg.grad_clip,
+                              cfg.grad_clip / (gnorm + 1e-12), 1.0) \
+                if cfg.grad_clip else jnp.float32(1.0)
 
-    c1 = 1.0 - cfg.b1 ** count.astype(jnp.float32)
-    c2 = 1.0 - cfg.b2 ** count.astype(jnp.float32)
+        c1 = 1.0 - cfg.b1 ** count.astype(jnp.float32)
+        c2 = 1.0 - cfg.b2 ** count.astype(jnp.float32)
 
-    def upd(g, m, v, w):
-        g = g.astype(jnp.float32) * scale
-        m32 = cfg.b1 * m.astype(jnp.float32) + (1 - cfg.b1) * g
-        v32 = cfg.b2 * v.astype(jnp.float32) + (1 - cfg.b2) * g * g
-        mhat = m32 / c1
-        vhat = v32 / c2
-        step = mhat / (jnp.sqrt(vhat) + cfg.eps) + cfg.weight_decay * w
-        w = w - lr * step
-        return (m32.astype(cfg.moments_dtype), v32.astype(cfg.moments_dtype),
-                w)
+        def upd(g, m, v, w):
+            g = g.astype(jnp.float32) * scale
+            m32 = cfg.b1 * m.astype(jnp.float32) + (1 - cfg.b1) * g
+            v32 = cfg.b2 * v.astype(jnp.float32) + (1 - cfg.b2) * g * g
+            mhat = m32 / c1
+            vhat = v32 / c2
+            step = mhat / (jnp.sqrt(vhat) + cfg.eps) + cfg.weight_decay * w
+            w = w - lr * step
+            return (m32.astype(cfg.moments_dtype),
+                    v32.astype(cfg.moments_dtype), w)
 
-    flat_g, tdef = jax.tree.flatten(grads)
-    flat_m = tdef.flatten_up_to(opt["m"])
-    flat_v = tdef.flatten_up_to(opt["v"])
-    flat_w = tdef.flatten_up_to(params)
-    out = [upd(g, m, v, w) for g, m, v, w in zip(flat_g, flat_m, flat_v,
-                                                 flat_w)]
+        flat_g, tdef = jax.tree.flatten(grads)
+        flat_m = tdef.flatten_up_to(opt["m"])
+        flat_v = tdef.flatten_up_to(opt["v"])
+        flat_w = tdef.flatten_up_to(params)
+        out = [upd(g, m, v, w) for g, m, v, w in zip(flat_g, flat_m, flat_v,
+                                                     flat_w)]
     new_m = tdef.unflatten([o[0] for o in out])
     new_v = tdef.unflatten([o[1] for o in out])
     new_w = tdef.unflatten([o[2] for o in out])

@@ -1,20 +1,18 @@
 """Observability subsystem tests (obs/: metrics, trace, report).
 
 Fast tests run in-process: instrument semantics, jsonl tracer round-trip
-and kill-safety (truncated final line), replay dedupe, BENCH export /
-diff, and the gate math.  The measured-vs-projected comm crosschecks and
-the telemetry-under-failure replay run on 8 simulated devices via
-testing/subproc.py — the same groups ``make obs-smoke`` drives.
+and kill-safety (truncated final line), replay dedupe, and the gate math.
+The measured-vs-projected comm crosschecks and the telemetry-under-failure
+replay run on 8 simulated devices via testing/subproc.py — the same groups
+``make obs-smoke`` drives.
 """
-import json
 import os
 
 import pytest
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, Registry,
                                count_dispatch, get_registry, set_registry)
-from repro.obs.report import (GateFailure, bench_diff, comm_gate,
-                              export_snapshot, format_diff, overhead_gate,
+from repro.obs.report import (GateFailure, comm_gate, overhead_gate,
                               runtime_gate)
 from repro.obs.trace import (Tracer, get_tracer, read_events,
                              replay_counters, set_tracer)
@@ -205,39 +203,8 @@ def test_set_tracer_restores_disabled():
 
 
 # ---------------------------------------------------------------------------
-# report: export, diff, gate
+# report: the gate
 # ---------------------------------------------------------------------------
-
-def test_export_snapshot_schema(tmp_path):
-    r = Registry()
-    r.counter("train.steps").inc(4)
-    r.histogram("train.step.wall_ms").observe(10.0)
-    p = str(tmp_path / "BENCH_runtime.json")
-    doc = export_snapshot(p, registry=r, extra={"config": {"mesh": [4, 2]}})
-    assert doc["runtime"]["metrics"]["train.steps"] == 4
-    assert doc["runtime"]["config"]["mesh"] == [4, 2]
-    on_disk = json.load(open(p))
-    assert on_disk == doc
-
-
-def test_bench_diff_and_cli(tmp_path, capsys):
-    old = {"runtime": {"metrics": {"a": 100.0, "b": 1.0, "gone": 5}}}
-    new = {"runtime": {"metrics": {"a": 103.0, "b": 2.0, "added": 7}}}
-    rows = bench_diff(old, new, rel_tol=0.05)
-    keys = [r[0] for r in rows]
-    assert "runtime.metrics.a" not in keys          # 3% < 5% tol
-    assert "runtime.metrics.b" in keys              # 2x drift
-    assert "runtime.metrics.gone" in keys and "runtime.metrics.added" in keys
-    assert "no drift" == format_diff(bench_diff(old, old))
-
-    from repro.obs import report as report_mod
-    po, pn = str(tmp_path / "o.json"), str(tmp_path / "n.json")
-    json.dump(old, open(po, "w"))
-    json.dump(new, open(pn, "w"))
-    assert report_mod.main(["diff", po, pn]) == 0
-    assert report_mod.main(["diff", po, pn, "--fail-on-drift"]) == 1
-    capsys.readouterr()
-
 
 def test_comm_gate_tolerance():
     ok = comm_gate({"zero.qwz_gather": 1000.0}, {"zero.qwz_gather": 1005.0})
