@@ -135,46 +135,94 @@ def _bwd_src(stacked: Array, res_ws, z: ZeroConfig):
 # ring plumbing (shared by the fwd/bwd scans)
 # ---------------------------------------------------------------------------
 
+# Stack layout (DESIGN.md §3).  A TPU tiles the two minor dims of an array
+# (8 sublanes x 128 lanes for f32), so a flat per-layer buffer stacked as
+# [rows, N] puts the row index on the sublane axis: one row is a 512-byte
+# strip of every tile, and writing or reading it is a relayout.  Every
+# stack these scans index by layer or slot is therefore carried as
+# [rows, N/128, 128]: the row index is a major, untiled axis, one row is a
+# contiguous run of whole tiles, and the flat buffer goes in and out by a
+# reshape that is a bitcast.  A length that is no multiple of 128 keeps
+# the [rows, N] form (ZeRO++ shards always are: partition.alignment pads
+# them to world x 256).
+
+LANES = 128
+
+
+def _row_shape(length: int) -> Tuple[int, ...]:
+    """Shape one stack row of a flat ``length`` buffer is carried in."""
+    if length % LANES == 0:
+        return (length // LANES, LANES)
+    return (length,)
+
+
+def _to_row(flat: Array) -> Array:
+    return flat.reshape(_row_shape(flat.shape[0]))
+
+
+def _stack_row(stack: Array, i) -> Array:
+    """Row ``i`` of a stack (either layout) as the flat buffer."""
+    return lax.dynamic_index_in_dim(stack, i, axis=0,
+                                    keepdims=False).reshape(-1)
+
+
+def _put_row(stack: Array, flat: Array, i) -> Array:
+    """``stack`` with row ``i`` set to the flat buffer ``flat``."""
+    return lax.dynamic_update_index_in_dim(stack, _to_row(flat), i, axis=0)
+
+
 # ``step.ring_*`` scopes name the ring's own operations in the profiler
 # trace's op names (bench/scopes.py); they change no instruction.
 
 def _ring_read(ring: Array, slot: Array) -> Array:
     with annotate("step.ring_read"):
-        return lax.dynamic_index_in_dim(ring, slot, axis=0, keepdims=False)
+        return _stack_row(ring, slot)
 
 
 def _ring_write(ring: Array, buf: Array, slot: Array) -> Array:
     with annotate("step.ring_write"):
-        return lax.dynamic_update_index_in_dim(ring, buf, slot, axis=0)
+        return _put_row(ring, buf, slot)
 
 
 def _ring_seed(slots: Sequence[Array]) -> Array:
     with annotate("step.ring_seed"):
-        return jnp.stack(slots)
+        return jnp.stack([_to_row(s) for s in slots])
 
 
-def _bwd_ring_seed(src, n: int, k: int, gather: Callable) -> Array:
+def _ring_zeros(k: int, length: int) -> Array:
+    """A k-slot f32 ring of zeros (the backward's unreduced gradients)."""
+    with annotate("step.ring_seed"):
+        return jnp.zeros((k,) + _row_shape(length), jnp.float32)
+
+
+def _grad_stack(n: int, length: int, dtype) -> Array:
+    """The backward's per-layer reduced-gradient stack, uninitialized (as a
+    scan's own output stack is): every row is written before it is read."""
+    return lax.empty((n,) + _row_shape(length), dtype)
+
+
+def _bwd_ring_seed(src: Array, n: int, k: int, gather: Callable) -> Array:
     """Seed the backward weight ring: slot ``i % k`` holds step *i*'s
     gathered weights for the first k reverse iterations (i = n-k..n-1)."""
     slots: List[Optional[Array]] = [None] * k
     for i in range(n - k, n):
-        p = jax.tree.map(
-            lambda s: lax.dynamic_index_in_dim(s, i, axis=0, keepdims=False),
-            src)
-        slots[i % k] = gather(p)
+        slots[i % k] = gather(_stack_row(src, i))
     return _ring_seed(slots)
 
 
-def _ring_grad_tail(dWring_f: Array, dprevs: Array, n: int, k: int,
+def _ring_grad_tail(dWring_f: Array, dstack: Array, k: int,
                     z: ZeroConfig) -> Array:
-    """Stitch the full gradient stack back together after a reverse ring
-    scan: steps 0..k-1's unreduced gradients are left in the final ring
-    (slot j = step j) and reduced here; dprevs[i] is step i+k's in-scan
-    reduce (the top k slots were the dummy zero-reduces)."""
-    head = [grad_reduce(dWring_f[j], z)[None].astype(dprevs.dtype)
-            for j in range(k)]
+    """Finish the gradient stack after a reverse ring scan: steps 0..k-1's
+    unreduced gradients are left in the final ring (slot j = step j) and
+    are reduced into rows 0..k-1 here (the scan filled the rest, and
+    wrote its k dummy zero-reduces there first).  The stack leaves the
+    scan as the [n, N] cotangent of the master stack, in one relayout."""
     with annotate("step.ring_tail"):
-        return jnp.concatenate(head + [dprevs[: n - k]], axis=0)
+        for j in range(k):
+            dstack = _put_row(
+                dstack, grad_reduce(_ring_read(dWring_f, j), z), j)
+    with annotate("step.stack_out"):
+        return dstack.reshape(dstack.shape[0], -1)
 
 
 # ---------------------------------------------------------------------------
@@ -316,7 +364,7 @@ def _prefetched(f: Callable, z: ZeroConfig, f_fwd, f_bwd, spec, bwd_spec,
                     # re-partition the gathered weights into this
                     # device's secondary shard: zero extra communication
                     # (paper §3.2.1)
-                    res_w = cl.slice_secondary(W, z.secondary_axes)
+                    res_w = _to_row(cl.slice_secondary(W, z.secondary_axes))
                 else:
                     res_w = jnp.zeros((0,), W.dtype)  # bwd re-gathers primary
             ring2 = _ring_write(ring, W_next, slot)
@@ -383,28 +431,23 @@ def _prefetched(f: Callable, z: ZeroConfig, f_fwd, f_bwd, spec, bwd_spec,
         # dW of layer i+k rides a second ring: its reduce-scatter runs
         # inside layer i's iteration, overlapped with the recompute+vjp.
         # The first k (i = n-1..n-k) iterations reduce zeros (discarded).
-        full = stacked.shape[1] * cl.axis_size(z.dp_axes)
-        with annotate("step.ring_seed"):
-            dWring0 = jnp.zeros((k, full), jnp.float32)
+        dWring0 = _ring_zeros(k, stacked.shape[1] * cl.axis_size(z.dp_axes))
+        dstack0 = _grad_stack(n, stacked.shape[1], z.grad_dtype)
         aux_xs = auxs if f_bwd is not None \
             else jnp.zeros((n,), jnp.float32)
 
         def body(carry, sx):
             if bwd_spec is not None:
-                g_h, Wring, dWring, bg, sWring = carry
+                g_h, Wring, dWring, bg, dstack, sWring = carry
             else:
-                g_h, Wring, dWring, bg = carry
+                g_h, Wring, dWring, bg, dstack = carry
             i, x_f, x_i, h_in, ct_y, aux = sx
             slot = jnp.remainder(i, k)
             prev = jnp.remainder(i - k, n)
             # 1. reduce layer i+k's pending gradient     [no dep on 3.]
             dprev = grad_reduce(_ring_read(dWring, slot), z)
             # 2. prefetch layer i-k's backward gather    [no dep on 3.]
-            p_prev = jax.tree.map(
-                lambda s: lax.dynamic_index_in_dim(
-                    s, prev, axis=0, keepdims=False),
-                src)
-            W_prev = _bwd_gather(p_prev, z)
+            W_prev = _bwd_gather(_stack_row(src, prev), z)
             if bwd_spec is not None:
                 # 2b. ... and layer i-k's speculative chunk-0 buffer
                 s_prev = _bwd_gather(bwd_spec(auxs, prev), z)
@@ -428,19 +471,27 @@ def _prefetched(f: Callable, z: ZeroConfig, f_fwd, f_bwd, spec, bwd_spec,
                 dh, Wring2, dWring2, dprev, sWring2 = \
                     lax.optimization_barrier(
                         (dh, Wring2, dWring2, dprev, sWring2))
-                return (dh, Wring2, dWring2, bg, sWring2), (dprev, dx_f)
-            dh, Wring2, dWring2, dprev = lax.optimization_barrier(
-                (dh, Wring2, dWring2, dprev))
-            return (dh, Wring2, dWring2, bg), (dprev, dx_f)
+            else:
+                dh, Wring2, dWring2, dprev = lax.optimization_barrier(
+                    (dh, Wring2, dWring2, dprev))
+            # layer i+k's reduced gradient goes straight into its own row
+            # (the first k iterations' dummies land in rows 0..k-1, which
+            # _ring_grad_tail overwrites)
+            dstack = _put_row(dstack, dprev, jnp.remainder(i + k, n))
+            carry2 = (dh, Wring2, dWring2, bg, dstack)
+            if bwd_spec is not None:
+                carry2 += (sWring2,)
+            return carry2, dx_f
 
-        init = (ct_h, Wring0, dWring0, zero_b, sWring0) \
-            if bwd_spec is not None else (ct_h, Wring0, dWring0, zero_b)
-        (dh0, _, dWring_f, bg, *_), (dprevs, dxs_f) = lax.scan(
+        init = (ct_h, Wring0, dWring0, zero_b, dstack0)
+        if bwd_spec is not None:
+            init += (sWring0,)
+        (dh0, _, dWring_f, bg, dstack, *_), dxs_f = lax.scan(
             body, init,
             (jnp.arange(n, dtype=jnp.int32), xs_f, xs_i, h_ins, ct_ys,
              aux_xs),
             reverse=True)
-        dstacked = _ring_grad_tail(dWring_f, dprevs, n, k, z)
+        dstacked = _ring_grad_tail(dWring_f, dstack, k, z)
         dxs = _merge(dxs_f, _int_cotangents(xs_i, (n,)))
         dbargs = _merge(bg, _int_cotangents(bargs_i))
         dW0 = None if w0_meta is None \
@@ -620,12 +671,12 @@ def _chunk_hpz_vjp(f: Callable, z: ZeroConfig, _gather, w0_meta):
             dstacked = drows
         else:
             Wring0 = _bwd_ring_seed(sec, nc, k, _gather)
-            full = stacked.shape[1] * cl.axis_size(z.dp_axes)
-            with annotate("step.ring_seed"):
-                dWring0 = jnp.zeros((k, full), jnp.float32)
+            dWring0 = _ring_zeros(
+                k, stacked.shape[1] * cl.axis_size(z.dp_axes))
+            dstack0 = _grad_stack(nc, stacked.shape[1], z.grad_dtype)
 
             def body(carry, sx):
-                Wring, dWring, bg = carry
+                Wring, dWring, bg, dstack = carry
                 i, x_f, x_i, ct_y = sx
                 slot = jnp.remainder(i, k)
                 dprev = grad_reduce(_ring_read(dWring, slot), z)
@@ -643,13 +694,14 @@ def _chunk_hpz_vjp(f: Callable, z: ZeroConfig, _gather, w0_meta):
                 dWring2 = _ring_write(dWring, dWflat, slot)
                 Wring2, dWring2, dprev = lax.optimization_barrier(
                     (Wring2, dWring2, dprev))
-                return (Wring2, dWring2, bg), (dprev, dx_f)
+                dstack = _put_row(dstack, dprev, jnp.remainder(i + k, nc))
+                return (Wring2, dWring2, bg, dstack), dx_f
 
-            (_, dWring_f, bg), (dprevs, dxs_f) = lax.scan(
-                body, (Wring0, dWring0, zero_b),
+            (_, dWring_f, bg, dstack), dxs_f = lax.scan(
+                body, (Wring0, dWring0, zero_b, dstack0),
                 (jnp.arange(nc, dtype=jnp.int32), xs_f, xs_i, ct_ys),
                 reverse=True)
-            dstacked = _ring_grad_tail(dWring_f, dprevs, nc, k, z)
+            dstacked = _ring_grad_tail(dWring_f, dstack, k, z)
 
         dxs = _merge(dxs_f, _int_cotangents(xs_i, (nc,)))
         dbargs = _merge(bg, _int_cotangents(bargs_i))

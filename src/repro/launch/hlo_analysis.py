@@ -537,16 +537,17 @@ def _ring_slack(by_name: Dict[str, "Instr"], users: Dict[str, List[str]],
     The depth-k ring schedule dynamic-update-slices each prefetched buffer
     into a (k, ...) ring carried by the loop, so the value is first READ k
     iterations after the gather was issued.  Walk the collective's user
-    chain (through dequantize fusions / converts / reshapes, which keep
-    the leading shape) until an op inserts it into a buffer with one extra
-    leading dim — that dim is the ring depth.  No ring found (the value is
-    consumed directly, e.g. the classic double buffer's bare carry or a
-    synchronous gather) = 1.
+    chain (through dequantize fusions / converts / reshapes) until an op
+    inserts it into a buffer whose rows past the leading dim hold as many
+    elements as the value — ``(k, N)`` or the lane-dense ``(k, N/128,
+    128)`` — and that leading dim is the ring depth.  No ring found (the
+    value is consumed directly, e.g. the classic double buffer's bare
+    carry or a synchronous gather) = 1.
     """
     shapes = _first_type_dims(ins.type_str)
     if not shapes:
         return 1
-    base = shapes[0][1]
+    size = int(np.prod(shapes[0][1]))
     seen, stack = set(), [ins.name]
     while stack:
         cur = stack.pop()
@@ -562,8 +563,8 @@ def _ring_slack(by_name: Dict[str, "Instr"], users: Dict[str, List[str]],
                 continue
             udims = udims_list[0][1]
             if (ui.opcode in ("dynamic-update-slice", "fusion")
-                    and len(udims) == len(base) + 1 and udims[1:] == base
-                    and udims[0] >= 1):
+                    and len(udims) >= 2 and udims[0] >= 1
+                    and int(np.prod(udims[1:])) == size):
                 return udims[0]
             stack.append(u)
     return 1

@@ -1775,12 +1775,15 @@ def check_obs_runtime_gate():
 # ---------------------------------------------------------------------------
 
 def _scan_carry_ring_depths(jaxpr, width, out=None, seen=None):
-    """Max leading dim per dtype over scan CARRY avals shaped (d, width)
+    """Max leading dim per dtype over scan CARRY avals whose rows hold
+    ``width`` elements, (d, width) or lane-dense (d, width/128, 128),
     reachable from ``jaxpr`` (recursive) — the prefetch rings.
 
     The forward ring rides the scan carry as a stacked (k, P) buffer (P =
     padded per-layer flat size); xs/consts never have that shape, so the
-    (d, width) carry filter isolates the rings exactly.
+    carry filter isolates the rings exactly.  The backward's
+    reduced-gradient stack rides the carry too, with rows of the SHARD
+    (P / world), so it does not match.
     """
     from repro.launch.jaxpr_analysis import _sub_jaxprs
     out = {} if out is None else out
@@ -1794,7 +1797,8 @@ def _scan_carry_ring_depths(jaxpr, width, out=None, seen=None):
             ncar = eqn.params["num_carry"]
             for v in eqn.invars[nc:nc + ncar]:
                 a = v.aval
-                if getattr(a, "ndim", 0) == 2 and a.shape[1] == width:
+                if (getattr(a, "ndim", 0) >= 2
+                        and int(np.prod(a.shape[1:])) == width):
                     key = str(a.dtype)
                     out[key] = max(out.get(key, 0), int(a.shape[0]))
         for sub, _ in _sub_jaxprs(eqn):

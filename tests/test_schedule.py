@@ -121,6 +121,80 @@ def test_zeroconfig_prefetch_validation():
 
 
 # ---------------------------------------------------------------------------
+# stack layout: lane-dense rows and the flat fallback, bit-exact at depth
+# ---------------------------------------------------------------------------
+
+N_LAYERS = 4
+# per-layer flat lengths: a multiple of 128 (rows carried [N/128, 128])
+# and one that is not (rows kept flat); quant blocks of 64 divide both
+LENGTHS = {"lane-dense": 384, "flat": 320}
+
+
+def _ring_case(scan: str, length: int, prefetch: int):
+    """(loss, grads) of a toy stack through one ring scan at ``prefetch``
+    on a one-device ZeRO++ mesh (qwZ, hpZ, qgZ all on)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.schedule import (zero_apply_scan, zero_chunk_scan,
+                                     zero_chunk_scan_hpz)
+    from repro.core.zeropp import ZeroConfig
+    from repro.launch.mesh import make_mesh
+
+    z = ZeroConfig(prefetch=prefetch, qwz_block=64, qgz_block=64)
+    mesh = make_mesh((1, 1), z.dp_axes, devices=jax.devices()[:1])
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    stacked = jax.random.normal(ks[0], (N_LAYERS, length), jnp.float32)
+    h0 = jax.random.normal(ks[1], (length,), jnp.float32)
+    xs = jax.random.normal(ks[2], (N_LAYERS, length), jnp.float32)
+
+    def body(W, h, x):
+        h2 = jnp.tanh(h * W.astype(jnp.float32) + x)
+        return h2, jnp.sum(h2 * h2)
+
+    def loss(stacked, h0, xs):
+        if scan == "dense":
+            h, ys = zero_apply_scan(body, z)(stacked, h0, xs)
+            return jnp.sum(h) + jnp.sum(ys)
+        # chunks: no carry, h0 rides as a broadcast argument
+        def f(W, x, h):
+            return body(W, h, x)[0]
+
+        if scan == "moe-chunk":
+            ys = zero_chunk_scan(f, z)(stacked, xs, h0)
+        else:  # the nested hpZ recompute, from the saved secondary shards
+            _, sec = zero_chunk_scan(f, z, collect_secondary=True)(
+                stacked, xs, h0)
+            ys = zero_chunk_scan_hpz(f, z)(stacked, sec, xs, h0)
+        return jnp.sum(ys * ys)
+
+    fn = jax.shard_map(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                       mesh=mesh, in_specs=(P(), P(), P()),
+                       out_specs=(P(), (P(), P(), P())), check_vma=False)
+    val, grads = jax.jit(fn)(stacked, h0, xs)
+    return float(val), [np.asarray(g) for g in grads]
+
+
+@pytest.mark.parametrize("layout", list(LENGTHS))
+@pytest.mark.parametrize("scan", ["dense", "moe-chunk", "moe-chunk-hpz"])
+def test_ring_layout_bit_exact(scan, layout):
+    """Every ring depth gives the synchronous reference's loss and every
+    gradient leaf bit for bit, with the stacks lane-dense (length a
+    multiple of 128) and in the flat fallback."""
+    import numpy as np
+
+    l0, g0 = _ring_case(scan, LENGTHS[layout], 0)
+    for pf in (1, 2):
+        l, g = _ring_case(scan, LENGTHS[layout], pf)
+        assert l == l0, (scan, layout, pf, l, l0)
+        for a, b in zip(g0, g):
+            assert a.shape == b.shape and np.array_equal(a, b), \
+                (scan, layout, pf, float(np.abs(a - b).max()))
+
+
+# ---------------------------------------------------------------------------
 # analyze_overlap unit tests (synthetic HLO, no devices)
 # ---------------------------------------------------------------------------
 

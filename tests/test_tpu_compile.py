@@ -5,8 +5,10 @@ cannot see the TPU's tiling rule or its VMEM limit.  Here each kernel of
 the ZeRO++ train step (qwZ quantize/dequantize, the qgZ reorder-quantize
 and both fused reduce hops) and the INT8 serving-head GEMM is lowered and
 compiled by the TPU compiler for one chip of a ``v5e:2x2`` topology, at the
-gpt-350m buffer widths a world of 1 and of 4 devices sees.  Nothing runs:
-a described chip holds no arrays.
+gpt-350m buffer widths a world of 1 and of 4 devices sees.  The layer
+scan's prefetch ring and stacks are compiled the same way, to check the
+layouts the compiler gives them.  Nothing runs: a described chip holds no
+arrays.
 
 The topology is described inside a module fixture (only one process may
 load the TPU library at a time, so never at import), and the persistent
@@ -14,9 +16,11 @@ compilation cache is off around these compiles: entries written for a
 described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core.quant import QuantConfig
@@ -128,3 +132,86 @@ def test_dequant_matmul_head_compiles(chip, tokens):
             x.reshape(tokens, d), p.reshape(V, d), s.reshape(V, -1)),
         (tokens * d, jnp.bfloat16), (V * d, jnp.int8),
         (V * d // 256, jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# the layer scan's stacks: the layer or slot index on an untiled axis
+# ---------------------------------------------------------------------------
+
+# (layers, per-layer flat length) of gpt-350m and of starcoder2-3b's blocks
+SCANS = {"gpt-350m": (24, 16779264), "starcoder2-3b": (4, 133699584)}
+# instructions that only name or read the master stack [n, N] the scan is
+# given (ZeroState's layout, not the scan's own)
+_VIEWS = ("parameter", "get-tuple-element", "bitcast")
+_INSTR = re.compile(r"%(\S+) = (\w+)\[(\d+),(\d+)\]\{1,0:T\([^}]*\} "
+                    r"([\w-]+)\(")
+
+
+def _row_on_sublanes(text, rows, length):
+    """(name, type, opcode, op_name) of every instruction whose result is
+    a [r, length] array, r in ``rows``, laid out with r on the tile's
+    sublane axis ({1,0:T(...)}), other than views and row reads of the
+    master stack."""
+    found = []
+    for line in text.splitlines():
+        m = _INSTR.search(line)
+        if not m or int(m.group(4)) != length or int(m.group(3)) not in rows:
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        op = op.group(1) if op else ""
+        # the forward's row read of the master: an unscoped dynamic_slice
+        master_read = (op.endswith(("/dynamic_slice", "/slice"))
+                       and "step." not in op)
+        if m.group(5) in _VIEWS or master_read:
+            continue
+        found.append((m.group(1), f"{m.group(2)}[{m.group(3)},{length}]",
+                      m.group(5), op))
+    return found
+
+
+@pytest.mark.parametrize("model", list(SCANS))
+def test_layer_scan_stacks_lane_dense(chip, model):
+    """Compile the prefetched layer scan (world 1, hpZ, prefetch 1, an
+    elementwise body) forward and backward at the model's widths.  The
+    hpZ residual stack, the backward's gradient stack and the weight and
+    gradient rings are carried [rows, N/128, 128]; no [n, N] or [1, N]
+    array has its layer or slot on the tiled axis, except the one
+    ``step.stack_out`` relayout to the master stack's [n, N] cotangent."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.schedule import zero_apply_scan
+    from repro.core.zeropp import ZeroConfig
+
+    n, length = SCANS[model]
+    z = ZeroConfig(qwz=False, qgz=False, param_dtype=jnp.float32,
+                   prefetch=1)
+    mesh = Mesh(np.array(list(chip.device_set)).reshape(1, 1), z.dp_axes)
+
+    def body(W, h, x):
+        return h + jnp.sum(jnp.square(W.astype(jnp.float32))), None
+
+    def loss(stacked, h0):
+        h, _ = zero_apply_scan(body, z)(stacked, h0, None)
+        return h
+
+    fn = jax.jit(jax.shard_map(jax.grad(loss), mesh=mesh,
+                               in_specs=(P(), P()), out_specs=P(),
+                               check_vma=False))
+    rep = NamedSharding(mesh, P())
+    text = fn.lower(
+        jax.ShapeDtypeStruct((n, length), jnp.float32, sharding=rep),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+    ).compile().as_text()
+
+    bad = [x for x in _row_on_sublanes(text, (n, 1), length)
+           if "step.stack_out" not in x[3]]
+    assert not bad, bad
+    assert any("step.stack_out" in x[3]
+               for x in _row_on_sublanes(text, (n,), length))
+    lanes = length // 128
+    for stack in (f"bf16[{n},{lanes},128]",   # hpZ residuals
+                  f"f32[{n},{lanes},128]",    # reduced gradients
+                  f"bf16[1,{lanes},128]",     # weight ring
+                  f"f32[1,{lanes},128]"):     # unreduced-gradient ring
+        assert stack + "{2,1,0:T(" in text, stack
