@@ -2,7 +2,7 @@
 """Readings that set a cell's limits, on the chip.  Not part of a run.
 
     python bench/control.py --workload <cell> --seeds 1,2,...,12 \
-        [--what sound,control,half_batch,loss_altered,exchange,fp8] \
+        [--what sound,control,half_batch,loss_altered,exchange,shared,fp8] \
         [--fault-seeds 3]
 
 The cell's step is built once and, for each seed, driven through the
@@ -22,6 +22,9 @@ reference by ``bench/check.py`` and judged against the cell's limits:
   exchange      the program with the gradient exchange between chips left
                 out (``faults.leave_out_exchange``; cells on more than one
                 chip).  The step is built a second time;
+  shared        the program with the MoE layers' shared experts left out
+                (``faults.shared_expert_left_out``; configurations with
+                shared experts).  The step is built a second time;
   fp8           the reference computed in float8 put in the program's
                 place: printed, and not held to fail (PERF.md says why).
 
@@ -43,7 +46,8 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-WHAT = ("sound", "control", "half_batch", "loss_altered", "exchange", "fp8")
+WHAT = ("sound", "control", "half_batch", "loss_altered", "exchange",
+        "shared", "fp8")
 
 
 def judged(mine, base, limits):
@@ -113,9 +117,9 @@ def main(argv=None):
 
     def rebuilt(name, plant):
         try:
-            with plant():
+            with plant():       # planted while the step compiles
                 prog = run.Program(cell, devices)
-            got[name] = program_readings(prog, tr, cfg, few)
+                got[name] = program_readings(prog, tr, cfg, few)
         except Exception as e:
             crashed[name] = f"{type(e).__name__}: {e}"[:2000]
             print(f"{name}: {crashed[name]}", file=sys.stderr, flush=True)
@@ -124,6 +128,8 @@ def main(argv=None):
         rebuilt("control", faults.int4_gathers)
     if "exchange" in what and cell.chips > 1:
         rebuilt("exchange", faults.exchange_left_out)
+    if "shared" in what and cfg.get("n_shared"):
+        rebuilt("shared", faults.shared_expert_left_out)
     print(f"program readings {time.perf_counter() - t:.1f} s",
           file=sys.stderr, flush=True)
     import jax

@@ -1,28 +1,26 @@
 """Operations and bytes the algorithm needs, from a configuration's shapes.
 
 ``flops_per_token``: the model FLOPs of one training token, forward and
-backward, with no recompute: 6 x the matmul parameters (every projection
-and the unembedding; the embedding is a lookup) plus causal attention,
-whose score and value products cost 2 x seq x (heads x head_dim) a token
-a layer forward, half of it masked, times 3 for the backward.
+backward, with no recompute: 6 x the matmul parameters a token meets
+plus attention's score and value products, as the configuration's module
+counts them (``bench/models/<model>.py``).
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
+
+from bench import models
 
 
-def matmul_params(cfg: Dict) -> int:
-    d, H, K = cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"]
-    hd, ff, V = cfg["head_dim"], cfg["d_ff"], cfg["vocab"]
-    per_layer = d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * ff
-    return cfg["n_layers"] * per_layer + V * d
+def matmul_params(cfg: Dict):
+    return models.load(cfg).matmul_params(cfg)
 
 
 def attention_flops_per_token(cfg: Dict, seq: int) -> int:
-    return cfg["n_layers"] * 3 * 2 * seq * cfg["n_heads"] * cfg["head_dim"]
+    return models.load(cfg).attention_flops_per_token(cfg, seq)
 
 
-def flops_per_token(cfg: Dict, seq: int) -> int:
+def flops_per_token(cfg: Dict, seq: int):
     return 6 * matmul_params(cfg) + attention_flops_per_token(cfg, seq)
 
 
@@ -39,7 +37,8 @@ SCALE_BYTES = 4          # one fp32 scale per quantization block
 
 def quant_kernel_calls(shapes: Dict[str, tuple], world: int, intra: int,
                        prefetch: int, weight_bits: int = 8,
-                       grad_bits: int = 4, block: int = 256) -> Dict:
+                       grad_bits: int = 4, block: int = 256,
+                       cfg: Optional[Dict] = None) -> Dict:
     """Calls per step of the five quantize kernels on each device, and the
     least bytes they move, from the flat buffer shapes and the world.
 
@@ -48,21 +47,18 @@ def quant_kernel_calls(shapes: Dict[str, tuple], world: int, intra: int,
     two hops over an ``intra`` x ``world / intra`` mesh) quantizes the
     bf16 gradient reordered, dequantizes-reduces-requantizes the intra
     hop's contributions, and dequantizes-reduces the inter hop's into the
-    fp32 shard.  The layer stack's prefetch ring gathers and reduces
-    ``layers + ring depth`` times; the embedding and final norm once; the
-    unembedding once per vocabulary chunk.
+    fp32 shard.  How many gathers and reduces each group issues a step is
+    the configuration's module's count (``quant_sites``; the dense model's
+    without ``cfg``).
     """
-    rows = shapes["blocks"][0]
-    ring = min(prefetch, rows - 1) if rows >= 2 else 0
-    sites = {"embed": 1, "head": 1, "unemb": shapes["unemb"][0],
-             "blocks": rows + ring}
+    sites = models.load(cfg or {}).quant_sites(shapes, prefetch)
     wq, gq = weight_bits / 8, grad_bits / 8
 
     def scales(n):
         return SCALE_BYTES * n / block
 
     calls, nbytes = 0, 0.0
-    for group, count in sites.items():
+    for group, (gathers, reduces) in sites.items():
         n = shapes[group][-1]
         shard, hop = n / world, n / intra
         gather = (4 * shard + wq * shard + scales(shard)      # quantize
@@ -70,6 +66,6 @@ def quant_kernel_calls(shapes: Dict[str, tuple], world: int, intra: int,
         reduce = (2 * n + gq * n + scales(n)                  # reorder-quant
                   + gq * n + scales(n) + gq * hop + scales(hop)
                   + gq * hop + scales(hop) + 4 * shard)       # last hop
-        calls += 5 * count
-        nbytes += count * (gather + reduce)
+        calls += 2 * gathers + 3 * reduces
+        nbytes += gathers * gather + reduces * reduce
     return {"calls_per_step": calls, "bytes_per_step": nbytes}

@@ -1,13 +1,14 @@
 """Plain float32 reference of the training step, layer by layer.
 
-It follows the repository's dense block as it stands: RMSNorm with a
-``1 + offset`` scale, RoPE on rotated halves, causal GQA attention, a gated
-MLP (fused gate|up, SiLU or tanh-GELU), residuals, a final RMSNorm and an
-untied unembedding, with the loss summed over tokens and divided by the
-global token count.  The optimizer is AdamW with global-norm clipping and
+The layers are the configuration's own (``bench/models/<model>.py``,
+found by the configuration's ``"model"`` key): each layer's float32
+forward pass, with the term it adds to the loss.  Around them: the
+embedding lookup, a final RMSNorm and an untied unembedding, with the loss
+summed over tokens and divided by the global token count, plus each
+layer's term.  The optimizer is AdamW with global-norm clipping and
 decoupled weight decay, as the traffic file states it.  Every matmul runs
 at ``Precision.HIGHEST``.  Nothing here imports the program: the weights
-come from :mod:`bench.weights`, the rows from :mod:`bench.traffic`.
+come from :mod:`bench.weights`, the rows from :mod:`bench.tokens`.
 
 Memory: parameters, both Adam moments and one layer's working set live on
 one device.  Each step runs the forward once, keeping only the layer
@@ -25,19 +26,18 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import models, weights
+from bench.models.dense import rms_norm
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0          # largest finite float8_e4m3fn
-NORM_EPS = 1e-6         # the blocks' RMSNorm epsilon
 HEAD_TOKENS = 2048      # tokens per unembedding chunk
-ATTN_QUERIES = 1024     # queries per attention chunk
 
 
 def _f8(x):
@@ -68,23 +68,25 @@ def _einsum_f8(spec, a, b):
     return f(a, b)
 
 
-def rms_norm(x, offset):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + NORM_EPS) * (1.0 + offset)
-
-
-def rope(x, cos, sin):
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    c, s = cos[None, :, None, :], sin[None, :, None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
-
-
 def rope_tables(seq: int, head_dim: int, theta: float):
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * freqs
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def data_shards(traffic: Dict) -> Tuple[int, int]:
+    """(row groups, sequence pieces): how a step's batch is spread over
+    the chips in ZeRO's data parallelism.  The rows go over as many of the
+    mesh's axes, in order, as they divide into; the sequence over the
+    rest.  Each chip computes its share of the loss on its own tokens."""
+    rows, pieces, left = 1, 1, int(traffic["global_batch"])
+    for n in (int(x) for x in traffic["mesh"]):
+        if pieces == 1 and left % n == 0 and left >= n:
+            rows, left = rows * n, left // n
+        else:
+            pieces *= n
+    return rows, pieces
 
 
 def lr_at(opt: Dict, count: int) -> float:
@@ -115,59 +117,39 @@ class Reference:
             raise ValueError(precision)
         self.cfg, self.opt = cfg, traffic["optimizer"]
         self.dot = _einsum if precision == "fp32" else _einsum_f8
-        self.leaf_shapes = dict(weights.leaves(cfg))
-        self.layer_names = [n for n, _ in weights.layer_leaves(cfg)]
-        self._block_fwd = jax.jit(self._block)
-        self._block_vjp = jax.jit(self._block_vjp_impl)
+        self.model = models.load(cfg)
+        self.shards = data_shards(traffic)
+        leaves = self.model.leaves(cfg)
+        self.leaf_shapes = {n: s for n, s, _ in leaves}
+        self.leaf_std = {n: std for n, _, std in leaves}
+        L = cfg["n_layers"]
+        self.layer_names = [[n for n, _, _ in self.model.layer_leaves(cfg, l)]
+                            for l in range(L)]
+        # one compile per kind of layer, from its first layer
+        first: Dict[str, int] = {}
+        for l in range(L):
+            first.setdefault(self.model.layer_kind(cfg, l), l)
+        fwd = {k: jax.jit(partial(self._block, l)) for k, l in first.items()}
+        vjp = {k: jax.jit(partial(self._block_vjp_impl, l))
+               for k, l in first.items()}
+        kinds = [self.model.layer_kind(cfg, l) for l in range(L)]
+        self._block_fwd = [fwd[k] for k in kinds]
+        self._block_vjp = [vjp[k] for k in kinds]
         self._head = jax.jit(self._head_impl)
         self._adam = jax.jit(self._adam_impl, donate_argnums=(0, 1, 2))
         self._leaf = jax.jit(weights.leaf_value, static_argnums=(4,))
 
     # ------------------------------------------------------------- model
 
-    def _attention(self, q, k, v):
-        B, S, H, hd = q.shape
-        rep = H // k.shape[2]
-        kr, vr = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-        C = min(ATTN_QUERIES, S)
-        kpos = jnp.arange(S)
+    def _block(self, l, W, h, cos, sin):
+        """Layer ``l``'s output and its term of the loss."""
+        return self.model.block(self.cfg, l, self.dot, W, h, cos, sin,
+                                self.shards)
 
-        @jax.checkpoint
-        def chunk(args):
-            qc, q0 = args
-            logits = self.dot("bqhd,bkhd->bhqk", qc, kr) * hd ** -0.5
-            mask = (q0 + jnp.arange(C))[:, None] >= kpos[None, :]
-            logits = jnp.where(mask[None, None], logits, -1e30)
-            p = jax.nn.softmax(logits, axis=-1)
-            return self.dot("bhqk,bkhd->bqhd", p, vr)
-
-        qs = q.reshape(B, S // C, C, H, hd).transpose(1, 0, 2, 3, 4)
-        out = jax.lax.map(chunk, (qs, jnp.arange(S // C) * C))
-        return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, hd)
-
-    def _block(self, W, h, cos, sin):
-        cfg, dot = self.cfg, self.dot
-        B, S, d = h.shape
-        H, K, hd = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-        x = rms_norm(h, W["ln1"])
-        q = dot("bsd,de->bse", x, W["wq"]).reshape(B, S, H, hd)
-        k = dot("bsd,de->bse", x, W["wk"]).reshape(B, S, K, hd)
-        v = dot("bsd,de->bse", x, W["wv"]).reshape(B, S, K, hd)
-        o = self._attention(rope(q, cos, sin), rope(k, cos, sin), v)
-        h = h + dot("bse,ed->bsd", o.reshape(B, S, H * hd), W["wo"])
-        x = rms_norm(h, W["ln2"])
-        g, u = jnp.split(dot("bsd,df->bsf", x, W["wgu"]), 2, axis=-1)
-        if cfg["act"] == "silu":
-            a = jax.nn.silu(g)
-        elif cfg["act"] == "gelu":
-            a = jax.nn.gelu(g, approximate=True)
-        else:
-            raise ValueError(cfg["act"])
-        return h + dot("bsf,fd->bsd", a * u, W["wdn"])
-
-    def _block_vjp_impl(self, W, h, cos, sin, g):
-        _, vjp = jax.vjp(lambda W_, h_: self._block(W_, h_, cos, sin), W, h)
-        return vjp(g)
+    def _block_vjp_impl(self, l, W, h, cos, sin, g):
+        _, vjp = jax.vjp(lambda W_, h_: self._block(l, W_, h_, cos, sin),
+                         W, h)
+        return vjp((g, jnp.ones((), jnp.float32)))
 
     def _head_impl(self, fnorm, unemb, h, targets, inv_tokens):
         """Summed NLL of a chunk of tokens and its gradients (scaled to
@@ -196,10 +178,10 @@ class Reference:
         shape = self.leaf_shapes[name]
         lid, nid, _ = weights.leaf_ids(name)
         return self._leaf(kd, np.uint32(lid), np.uint32(nid),
-                          np.float32(weights.leaf_std(name, shape)), shape)
+                          np.float32(self.leaf_std[name]), shape)
 
     def _layer(self, P, l):
-        return {n: P[f"layer{l}.{n}"] for n in self.layer_names}
+        return {n: P[f"layer{l}.{n}"] for n in self.layer_names[l]}
 
     def run(self, seed: int, batches: List[Dict[str, np.ndarray]]
             ) -> Readings:
@@ -219,9 +201,11 @@ class Reference:
             targets = jnp.asarray(batch["targets"]).reshape(-1)
             B, S = tokens.shape
             T = B * S
-            hs = [P["emb"][tokens]]
+            hs, aux = [P["emb"][tokens]], []
             for l in range(L):
-                hs.append(self._block_fwd(self._layer(P, l), hs[-1], cos, sin))
+                h, a = self._block_fwd[l](self._layer(P, l), hs[-1], cos, sin)
+                hs.append(h)
+                aux.append(a)
             hl = hs[-1].reshape(T, -1)
             loss, d_fn, d_un, g_parts = 0.0, 0.0, 0.0, []
             for a in range(0, T, HEAD_TOKENS):
@@ -232,12 +216,13 @@ class Reference:
                 loss, d_fn, d_un = loss + float(lc), d_fn + fc, d_un + uc
                 g_parts.append(gc)
             g_top = jnp.concatenate(g_parts).reshape(hs[-1].shape)
-            losses.append(loss / T)
+            losses.append(loss / T + float(sum(aux)))
             grads = {"fnorm": d_fn, "unemb": d_un}
             sumsq = {k: float(jnp.sum(v * v)) for k, v in grads.items()}
             g = g_top
             for l in reversed(range(L)):            # backward 1: norms
-                dW, g = self._block_vjp(self._layer(P, l), hs[l], cos, sin, g)
+                dW, g = self._block_vjp[l](self._layer(P, l), hs[l], cos,
+                                           sin, g)
                 for n, x in dW.items():
                     sumsq[f"layer{l}.{n}"] = float(jnp.sum(x * x))
                 del dW
@@ -258,7 +243,8 @@ class Reference:
 
             g = g_top
             for l in reversed(range(L)):            # backward 2: update
-                dW, g = self._block_vjp(self._layer(P, l), hs[l], cos, sin, g)
+                dW, g = self._block_vjp[l](self._layer(P, l), hs[l], cos,
+                                           sin, g)
                 for n, x in dW.items():
                     update(f"layer{l}.{n}", x)
                 del dW

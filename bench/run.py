@@ -152,8 +152,12 @@ class Program:
         rep = NamedSharding(self.mesh, P())
 
         def rows_of(x, r):
-            return x if x.ndim == 1 else jax.lax.dynamic_index_in_dim(
-                x, r, keepdims=False)
+            """Row ``r`` of a group, its rows counted flat."""
+            if x.ndim == 1:
+                return x
+            if x.ndim > 2:
+                x = x.reshape(-1, x.shape[-1])
+            return jax.lax.dynamic_index_in_dim(x, r, keepdims=False)
 
         self._sumsq = {
             g: jax.jit(lambda x, g=g: jax.lax.map(
@@ -195,12 +199,17 @@ class Program:
 
 
 def _check_precision(stated, pol):
-    """The program has to run as the configuration states."""
+    """The program has to run as the configuration states.  Unquantized
+    gathers and reduces move their wire dtype's bits."""
     z = pol.zcfg
+
+    def bits(dtype):
+        return jnp.dtype(dtype).itemsize * 8
+
     got = {"compute": jnp.dtype(z.compute_dtype).name,
            "moments": jnp.dtype(pol.moments_dtype).name,
-           "weight_gather_bits": z.qwz_bits if z.qwz else 16,
-           "grad_reduce_bits": z.qgz_bits if z.qgz else 16}
+           "weight_gather_bits": z.qwz_bits if z.qwz else bits(z.param_dtype),
+           "grad_reduce_bits": z.qgz_bits if z.qgz else bits(z.reduce_dtype)}
     want = {k: stated[k] for k in got}
     if got != want:
         raise RuntimeError(f"program runs {got}, configuration states "
@@ -395,7 +404,8 @@ def _trace_context(cell, prog, params, opt, batch, tdir, steps, elapsed,
             "device": device, "wire_by_label": wire,
             "kernel_calls": flops.quant_kernel_calls(
                 prog.model.param_shapes(), len(prog.mesh.devices.flat),
-                int(sizes["model"]), prog.model.zcfg.prefetch),
+                int(sizes["model"]), prog.model.zcfg.prefetch,
+                cfg=cell.config),
             }
 
 

@@ -4,6 +4,7 @@ A cell (one entry of ``workloads``) names a configuration and a traffic
 mix.  Each lives in a file of its own, found by name:
 
   bench/configs/<config>.json     the model as it is run, with its source
+  bench/models/<model>.py         its layers, named by the file's "model"
   bench/traffic/<traffic>.json    batch, sequence, mesh, optimizer, data
   bench/workloads/<cell>.json     the limits that decide ``correct``
   bench/metrics/<metric>.py       one reader per per-layer metric
@@ -22,9 +23,13 @@ from typing import Any, Dict, List, Optional
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 
-# keys of a configuration file that describe it rather than set a size
+# keys of a configuration file that describe it rather than set a size;
+# ``model`` names its layers' module (bench/models/<model>.py), and
+# ``reference_only`` lists keys that state behaviour the program fixes in
+# code: the reference reads them, the program has no option for them
 CONFIG_META = ("arch", "source", "reduced", "published", "assumed",
-               "departures", "deployment", "precision")
+               "departures", "deployment", "precision", "model",
+               "reference_only")
 
 
 # what the harness reads from a traffic file; any other key is refused, so
@@ -66,7 +71,8 @@ def _applies(metric: Dict[str, Any], cell: str) -> bool:
 
 def arch_overrides(config: Dict[str, Any]) -> Dict[str, Any]:
     """The sizes a configuration file sets, as ``ArchConfig`` fields."""
-    out = {k: v for k, v in config.items() if k not in CONFIG_META}
+    skip = set(CONFIG_META) | set(config.get("reference_only", ()))
+    out = {k: v for k, v in config.items() if k not in skip}
     if "pattern" in out:
         out["pattern"] = tuple(out["pattern"])
     return out
